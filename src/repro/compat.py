@@ -1,23 +1,12 @@
-"""JAX version compatibility helpers.
+"""Mesh helpers shared by every mesh construction and installation.
 
-The mesh APIs moved between JAX releases:
-
-* ``jax.sharding.AxisType`` + the ``axis_types=`` kwarg of
-  ``jax.make_mesh`` only exist on newer JAX (>= 0.5.x); on 0.4.x meshes
-  are constructed without axis types.
-* ``jax.set_mesh`` (and its predecessor ``jax.sharding.use_mesh``) do not
-  exist on 0.4.x, where entering the ``Mesh`` context manager is the way
-  to install a global mesh.
-* ``jax.sharding.AbstractMesh`` takes ``(axis_sizes, axis_names)`` on new
-  JAX but a single ``((name, size), ...)`` tuple on 0.4.x.
-
-Every mesh construction / installation in this repo goes through these
-helpers so the rest of the codebase is version-agnostic.
+Thin wrappers over the installed JAX's mesh APIs (``jax.make_mesh`` with
+Auto axis types, ``jax.set_mesh``, ``jax.shard_map``,
+``jax.lax.axis_size``) so call sites stay short and uniform.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Sequence
 
 import jax
@@ -25,67 +14,30 @@ import jax
 __all__ = ["make_mesh", "set_mesh", "make_abstract_mesh", "shard_map",
            "axis_size"]
 
+shard_map = jax.shard_map
+
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis inside a shard_map island.
-
-    ``jax.lax.axis_size`` only exists on newer JAX; on 0.4.x
-    ``jax.core.axis_frame(name)`` resolves to the (static) size.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.core as jc
-    return int(jc.axis_frame(axis_name))
-
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # moved out of jax.experimental (and check_rep -> check_vma) later
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f=None, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if f is None:
-            return lambda g: _shard_map_old(g, **kwargs)
-        return _shard_map_old(f, **kwargs)
+    """Static size of a named mesh axis inside a shard_map island."""
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _HAS_AXIS_TYPE:
-        kwargs["axis_types"] = \
-            (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         **kwargs)
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Prefers ``jax.set_mesh`` (new), then ``jax.sharding.use_mesh``, and
-    falls back to the classic ``Mesh`` context manager on 0.4.x.
-    """
-    if hasattr(jax, "set_mesh"):
-        ctx = jax.set_mesh(mesh)
-        # jax.set_mesh is a context manager on recent versions; on some
-        # intermediates it sets state and returns None.
-        return ctx if ctx is not None else contextlib.nullcontext(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on 0.4.x
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def make_abstract_mesh(axis_shapes: Sequence[int],
                        axis_names: Sequence[str]):
     """Device-free mesh for sharding-rule metadata (no allocation)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes),
-                                         tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(tuple(axis_names), tuple(axis_shapes))))
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))

@@ -36,7 +36,10 @@ MXU tiles).
 
 Layout (GQA): q (B, Hq, Tq, D); k, v (B, Hkv, Tk, D); per-token metadata
 ``q_doc/q_pos`` (B, Tq) and ``kv_doc/kv_pos`` (B, Tk) int32; doc id < 0 is
-padding.  Visibility: same doc AND q_pos >= kv_pos.
+padding.  Visibility: same doc AND q_pos >= kv_pos.  The entry points
+re-tile the metadata and the lse/delta row statistics into the
+lane/sublane-broadcast layout the TPU block-shape rule admits (see
+``_tile_meta``) and return lse as (B, Hq, Tq).
 
 The pure-jnp oracle lives in ``ref.py``; jit'd wrappers + custom VJP in
 ``ops.py``.  All kernels are validated against the oracle with
@@ -514,11 +517,39 @@ def _build_block_tables_legacy(q_doc, q_pos, kv_doc, kv_pos, *, block_q,
 # ===================================================================== #
 # shared kernel helpers
 # ===================================================================== #
+# TPU block-shape rule: the last two dims of every block are multiples of
+# (8, 128) or span the whole array.  Per-token vectors therefore enter the
+# kernels pre-tiled (the layout of JAX's own TPU flash attention): q-side
+# doc/pos and the per-row lse/delta statistics broadcast along lanes, so a
+# q block is a (block_q, LANES) column tile; kv-side doc/pos broadcast
+# along sublanes, so a kv block is a (SUBLANES, block_k) row tile.
+LANES = 128
+SUBLANES = 8
+
+
+def _tile_meta(q_doc, q_pos, kv_doc, kv_pos):
+    """(B, T) int32 metadata -> q-side (B, Tq, LANES), kv-side
+    (B, SUBLANES, Tk)."""
+    def col(x):
+        return jnp.broadcast_to(x[:, :, None], x.shape + (LANES,))
+
+    def row(x):
+        return jnp.broadcast_to(x[:, None, :],
+                                (x.shape[0], SUBLANES, x.shape[1]))
+
+    return col(q_doc), col(q_pos), row(kv_doc), row(kv_pos)
+
+
+def _row_stat(x):
+    """(B, H, T) f32 per-row statistic -> (B, H, T, LANES)."""
+    return jnp.broadcast_to(x[..., None], x.shape + (LANES,))
+
+
 def _visible(qd_ref, qp_ref, kd_ref, kp_ref):
-    qd = qd_ref[0, :][:, None]
-    qp = qp_ref[0, :][:, None]
-    kd = kd_ref[0, :][None, :]
-    kp = kp_ref[0, :][None, :]
+    qd = qd_ref[0, :, :1]                         # (bq, 1) column
+    qp = qp_ref[0, :, :1]
+    kd = kd_ref[0, :1, :]                         # (1, bk) row
+    kp = kp_ref[0, :1, :]
     return (qd == kd) & (qp >= kp) & (qd >= 0) & (kd >= 0)
 
 
@@ -563,10 +594,8 @@ def _fwd_finalize(out_ref, lse_ref, acc_ref, m_ref, l_ref):
     m = m_ref[:, :1]
     out = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
     out_ref[0, 0] = out.astype(out_ref.dtype)
-    lse = jnp.where(l[:, 0] > 0,
-                    m[:, 0] + jnp.log(jnp.maximum(l[:, 0], 1e-30)),
-                    -jnp.inf)
-    lse_ref[0, 0] = lse
+    lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), -jnp.inf)
+    lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
 def _fwd_kernel(kv_idx_ref, kv_nvis_ref,           # scalar prefetch
@@ -656,10 +685,10 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
             return (b, h // group, col[b, s], 0)
 
         def q_meta(b, h, s, row, col, flags):
-            return (b, row[b, s])
+            return (b, row[b, s], 0)
 
         def kv_meta(b, h, s, row, col, flags):
-            return (b, col[b, s])
+            return (b, 0, col[b, s])
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -668,16 +697,16 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
                 pl.BlockSpec((1, 1, block_q, D), q_map),
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_q),
+                pl.BlockSpec((1, 1, block_q, LANES),
                              lambda b, h, s, row, col, flags:
-                             (b, h, row[b, s])),
+                             (b, h, row[b, s], 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, D), jnp.float32),
@@ -695,7 +724,7 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
             return (b, h // group, kv_idx[b, qi, vi], 0)
 
         def kv_meta(b, h, qi, vi, kv_idx, kv_nvis):
-            return (b, kv_idx[b, qi, vi])
+            return (b, 0, kv_idx[b, qi, vi])
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -704,14 +733,14 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
                 pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, block_q), lambda b, h, qi, vi, *s: (b, qi)),
-                pl.BlockSpec((1, block_q), lambda b, h, qi, vi, *s: (b, qi)),
-                pl.BlockSpec((1, block_k), kv_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
+                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda b, h, qi, vi, *s: (b, h, qi)),
+                pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, D), jnp.float32),
@@ -726,11 +755,11 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Tq, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(*inputs, q, k, v, q_doc, q_pos, kv_doc, kv_pos)
-    return out, lse
+    )(*inputs, q, k, v, *_tile_meta(q_doc, q_pos, kv_doc, kv_pos))
+    return out, lse[..., 0]
 
 
 # ===================================================================== #
@@ -742,9 +771,9 @@ def _dq_visit(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     k = k_ref[0, 0]
     v = v_ref[0, 0]
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, None]                      # (bq, 1)
+    lse = lse_ref[0, 0, :, :1]                        # (bq, 1)
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    delta = dl_ref[0, 0][:, None]
+    delta = dl_ref[0, 0, :, :1]
 
     s = _dot_f32(q, k.T.astype(jnp.float32)) * scale
     vis = _visible(qd_ref, qp_ref, kd_ref, kp_ref)
@@ -820,16 +849,16 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
             return (b, h, row[b, s], 0)
 
         def q_vec(b, h, s, row, col, flags):
-            return (b, h, row[b, s])
+            return (b, h, row[b, s], 0)
 
         def kv_map(b, h, s, row, col, flags):
             return (b, h // group, col[b, s], 0)
 
         def q_meta(b, h, s, row, col, flags):
-            return (b, row[b, s])
+            return (b, row[b, s], 0)
 
         def kv_meta(b, h, s, row, col, flags):
-            return (b, col[b, s])
+            return (b, 0, col[b, s])
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -839,12 +868,12 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
                 pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_q), q_vec),
-                pl.BlockSpec((1, 1, block_q), q_vec),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
+                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
+                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
             ],
             out_specs=[pl.BlockSpec((1, 1, block_q, D), q_map)],
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -859,7 +888,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
             return (b, h // group, kv_idx[b, qi, vi], 0)
 
         def kv_meta(b, h, qi, vi, kv_idx, kv_nvis):
-            return (b, kv_idx[b, qi, vi])
+            return (b, 0, kv_idx[b, qi, vi])
 
         def q_block(b, h, qi, vi, *s):
             return (b, h, qi, 0)
@@ -872,12 +901,12 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_q), lambda b, h, qi, vi, *s: (b, h, qi)),
-                pl.BlockSpec((1, 1, block_q), lambda b, h, qi, vi, *s: (b, h, qi)),
-                pl.BlockSpec((1, block_q), lambda b, h, qi, vi, *s: (b, qi)),
-                pl.BlockSpec((1, block_q), lambda b, h, qi, vi, *s: (b, qi)),
-                pl.BlockSpec((1, block_k), kv_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
+                pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
+                pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
+                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
             ],
             out_specs=[pl.BlockSpec((1, 1, block_q, D), q_block)],
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -889,7 +918,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, Tq, D), q.dtype)],
         interpret=interpret,
-    )(*inputs, q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos)
+    )(*inputs, q, k, v, do, _row_stat(lse), _row_stat(delta),
+      *_tile_meta(q_doc, q_pos, kv_doc, kv_pos))
     return dq
 
 
@@ -902,9 +932,9 @@ def _dkv_visit(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     k = k_ref[0, 0]
     v = v_ref[0, 0]
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, None]
+    lse = lse_ref[0, 0, :, :1]
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    delta = dl_ref[0, 0][:, None]
+    delta = dl_ref[0, 0, :, :1]
 
     s = _dot_f32(q, k.T.astype(jnp.float32)) * scale    # (bq, bk)
     vis = _visible(qd_ref, qp_ref, kd_ref, kp_ref)
@@ -986,16 +1016,16 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
             return (b, hkv * group + gi, col[b, s], 0)
 
         def q_vec(b, hkv, s, gi, row, col, flags):
-            return (b, hkv * group + gi, col[b, s])
+            return (b, hkv * group + gi, col[b, s], 0)
 
         def q_meta(b, hkv, s, gi, row, col, flags):
-            return (b, col[b, s])
+            return (b, col[b, s], 0)
 
         def kv_block(b, hkv, s, gi, row, col, flags):
             return (b, hkv, row[b, s], 0)
 
         def kv_meta(b, hkv, s, gi, row, col, flags):
-            return (b, row[b, s])
+            return (b, 0, row[b, s])
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -1005,12 +1035,12 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_q), q_vec),
-                pl.BlockSpec((1, 1, block_q), q_vec),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
+                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
+                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
@@ -1032,16 +1062,16 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
             return (b, hkv * group + gi, q_idx[b, ki, vqi], 0)
 
         def q_vec(b, hkv, ki, vqi, gi, q_idx, q_nvis):
-            return (b, hkv * group + gi, q_idx[b, ki, vqi])
+            return (b, hkv * group + gi, q_idx[b, ki, vqi], 0)
 
         def q_meta(b, hkv, ki, vqi, gi, q_idx, q_nvis):
-            return (b, q_idx[b, ki, vqi])
+            return (b, q_idx[b, ki, vqi], 0)
 
         def kv_block(b, hkv, ki, vqi, gi, *s):
             return (b, hkv, ki, 0)
 
         def kv_meta(b, hkv, ki, vqi, gi, *s):
-            return (b, ki)
+            return (b, 0, ki)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1051,12 +1081,12 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
                 pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_q), q_vec),
-                pl.BlockSpec((1, 1, block_q), q_vec),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_q), q_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
-                pl.BlockSpec((1, block_k), kv_meta),
+                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
+                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, block_q, LANES), q_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_k, D), kv_block),
@@ -1078,5 +1108,6 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
             jax.ShapeDtypeStruct((B, Hkv, Tk, D), v.dtype),
         ],
         interpret=interpret,
-    )(*inputs, q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos)
+    )(*inputs, q, k, v, do, _row_stat(lse), _row_stat(delta),
+      *_tile_meta(q_doc, q_pos, kv_doc, kv_pos))
     return dk, dv

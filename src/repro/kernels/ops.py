@@ -43,7 +43,15 @@ from repro.table_layout import GRID_TABLE_HALF
 from . import doc_attention as da
 from .ref import doc_mask
 
-__all__ = ["doc_flash_attention", "doc_attention_xla"]
+__all__ = ["doc_flash_attention", "doc_attention_xla", "resolve_interpret"]
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` -> Pallas interpret mode exactly when JAX's default backend
+    is not a TPU; on a chip the kernels always compile with Mosaic."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def _float0_zero(x):
@@ -256,7 +264,10 @@ def doc_attention_xla(
         qs = q.reshape(B, Hq, nq, q_chunk, D).transpose(2, 0, 1, 3, 4)
         qds = q_doc.reshape(B, nq, q_chunk).transpose(1, 0, 2)
         qps = q_pos.reshape(B, nq, q_chunk).transpose(1, 0, 2)
-        outs = jax.lax.map(one_chunk, (qs, qds, qps))   # (nq, B, Hq, qc, *)
+        # checkpointed: the backward recomputes each chunk's logits
+        # instead of saving all nq of them (the full (Tq, Tk) matrix)
+        outs = jax.lax.map(jax.checkpoint(one_chunk),
+                           (qs, qds, qps))              # (nq, B, Hq, qc, *)
         if partial:
             o, m, l = outs
             return (o.transpose(1, 2, 0, 3, 4).reshape(B, Hq, Tq, D),

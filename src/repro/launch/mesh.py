@@ -7,7 +7,7 @@ Multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model").
 tokens over it; parameters are additionally fully sharded over every axis
 (FSDP, runtime/sharding.py).  A *function*, not a module constant: importing
 this module must never touch JAX device state.  Construction goes through
-:mod:`repro.compat` so it works across JAX versions.
+:func:`repro.compat.make_mesh` (Auto axis types).
 """
 
 from __future__ import annotations

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.configs.base import RunConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.serve import EngineKilled, ServeEngine, parse_chaos
 
 _RC = RunConfig()   # serve defaults live on RunConfig (single source)
@@ -289,6 +290,7 @@ def main():
     ap.add_argument("--max-steps", type=int, default=100_000,
                     dest="max_steps")
     args = ap.parse_args()
+    setup_compile_cache()
     serve(args)
 
 

@@ -18,6 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.core.cp_attention import make_cp_context
+from repro.kernels.ops import resolve_interpret
 from repro.planner import get_planner, pick_buffer_bucket
 from repro.models import decode_step as model_decode_step
 from repro.models import forward, init_cache, init_params, loss_fn
@@ -162,7 +163,7 @@ def build_train_step(cfg: ModelConfig, mesh, run: RunConfig,
                      shape: ShapeConfig, *, abstract: bool = True,
                      q_chunk: int = 512, block_q: int = 128,
                      block_k: int = 128,
-                     interpret: bool = False,
+                     interpret: bool | None = None,
                      accum: int = 1) -> StepBundle:
     """One fused (loss + grad + optimizer) training step.
 
@@ -176,7 +177,10 @@ def build_train_step(cfg: ModelConfig, mesh, run: RunConfig,
     rows locally).  Gradients are token-weighted across micros, so the
     accumulated update equals the fused one on the same batch: the
     global masked CE mean is ``Σ ce_sum / Σ tokens`` either way.
+
+    ``interpret=None`` runs Pallas kernels in interpret mode only off-TPU.
     """
+    interpret = resolve_interpret(interpret)
     plan_strategy = effective_strategy(cfg, run.cp_strategy)
     exec_strategy = exec_strategy_of(plan_strategy)
     baxes = batch_axes_of(mesh)
@@ -280,7 +284,8 @@ def build_train_step(cfg: ModelConfig, mesh, run: RunConfig,
 def build_prefill_step(cfg: ModelConfig, mesh, run: RunConfig,
                        shape: ShapeConfig, *, q_chunk: int = 512,
                        block_q: int = 128, block_k: int = 128,
-                       interpret: bool = False) -> StepBundle:
+                       interpret: bool | None = None) -> StepBundle:
+    interpret = resolve_interpret(interpret)
     plan_strategy = effective_strategy(cfg, run.cp_strategy)
     exec_strategy = exec_strategy_of(plan_strategy)
     baxes = batch_axes_of(mesh)

@@ -42,9 +42,10 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 from repro.compat import set_mesh
 from repro.configs import RunConfig, get_config, reduce_for_smoke
-from repro.configs.base import ShapeConfig
+from repro.configs.base import ModelConfig, ShapeConfig
 from repro.data.pipeline import (PipelineConfig, Prefetcher,
                                  make_batch, make_dispatch_batch)
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import (make_group_mesh, make_local_mesh,
                                make_production_mesh)
 from repro.launch.steps import build_train_step, effective_strategy
@@ -92,6 +93,16 @@ def _ft_setup(args, n_dev: int, model_axis: int):
     injector = FailureInjector(fail_step, fail_hosts)
     sim = StragglerSim(factors)
     return topology, policy, monitor, injector, sim
+
+
+def _final_save(ckpt: CheckpointManager, step: int, state) -> float:
+    """Blocking save of the whole final state; returns its seconds."""
+    t0 = time.perf_counter()
+    ckpt.save(step, state, blocking=True)
+    dt = time.perf_counter() - t0
+    print(f"[train] final checkpoint (step {step}) saved to "
+          f"{ckpt.directory} in {dt:.2f}s", flush=True)
+    return dt
 
 
 def _effective_accum(batch: int, groups: int, accum: int) -> int:
@@ -299,22 +310,67 @@ def _train_dispatch(args, cfg, run: RunConfig, mesh_axes) -> dict:
 
     final = supervisor.run(one_step, start_step=start,
                            total_steps=args.steps, on_restore=on_restore)
-    ckpt.save(final, state, blocking=True)
+    save_s = _final_save(ckpt, final, state)
     if it:
         it.close()
     print(f"[train] dispatch: {switches[0]} degree switches over "
           f"{args.steps} steps; tilings used: {sorted(bundles)}")
-    return {"final_step": final, "losses": losses,
+    return {"final_step": final, "losses": losses, "ckpt_save_s": save_s,
             "recoveries": policy.restarts,
             "dead_hosts": sorted(supervisor.dead),
             "mesh": (cur["data"], M), "accum": cur["accum"],
             "degree_switches": switches[0]}
 
 
-def train(args) -> dict:
+def model_config(args) -> ModelConfig:
+    """``--arch`` resolved, reduced by ``--smoke``, cut by ``--layers``."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
+    layers = getattr(args, "layers", 0)
+    if layers:
+        full = cfg.num_layers
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+        print(f"[train] depth cut: {cfg.name} at {layers} of {full} layers, "
+              f"widths unchanged ({cfg.param_count() / 1e9:.3f}B params)",
+              flush=True)
+    return cfg
+
+
+def run_config(args) -> RunConfig:
+    """The :class:`RunConfig` the driver trains under for ``args``."""
+    steps = args.steps
+    return RunConfig(arch=args.arch, cp_strategy=args.strategy,
+                     attention_impl=args.attention_impl, lr=args.lr,
+                     total_steps=steps, warmup_steps=max(steps // 10, 1),
+                     grad_compression=args.grad_compression,
+                     checkpoint_dir=args.checkpoint_dir,
+                     remat=not args.no_remat,
+                     dispatch="adaptive" if getattr(args, "dispatch", False)
+                     else "off",
+                     dispatch_target_imbalance=getattr(args, "dispatch_target",
+                                                       1.1),
+                     dispatch_min_cp=getattr(args, "dispatch_min_cp", 1),
+                     seed=getattr(args, "seed", 0))
+
+
+def pipeline_config(args, cfg: ModelConfig, run: RunConfig,
+                    cp: int) -> PipelineConfig:
+    """The per-rank batch stream of the non-dispatch loop."""
+    return PipelineConfig(
+        dataset=args.dataset, context_len=args.seq_len,
+        batch_per_host=args.batch, cp_size=cp,
+        strategy=effective_strategy(cfg, run.cp_strategy),
+        vocab_size=cfg.vocab_size, seed=run.seed,
+        # pallas tables need block-divisible rank slices
+        align=128 if run.attention_impl == "pallas"
+        else (1 if cp == 1 else 16),
+        emit_tables=(run.attention_impl == "pallas" and cfg.uses_attention),
+        table_overlap=run.cp_overlap, table_grid=run.kernel_grid)
+
+
+def train(args) -> dict:
+    cfg = model_config(args)
     if args.mesh == "prod":
         mesh = make_production_mesh()
     else:
@@ -323,17 +379,8 @@ def train(args) -> dict:
     cp = mesh.shape["model"]
     d_axis = mesh.shape["data"]
 
-    # dispatch flags default off for programmatic callers (SimpleNamespace)
-    dispatch = getattr(args, "dispatch", False)
-    run = RunConfig(arch=args.arch, cp_strategy=args.strategy,
-                    attention_impl=args.attention_impl, lr=args.lr,
-                    total_steps=args.steps, warmup_steps=max(args.steps // 10, 1),
-                    grad_compression=args.grad_compression,
-                    checkpoint_dir=args.checkpoint_dir, remat=not args.no_remat,
-                    dispatch="adaptive" if dispatch else "off",
-                    dispatch_target_imbalance=getattr(args, "dispatch_target",
-                                                      1.1),
-                    dispatch_min_cp=getattr(args, "dispatch_min_cp", 1))
+    run = run_config(args)
+    dispatch = run.dispatch == "adaptive"
     shape = ShapeConfig("custom", args.seq_len, args.batch, "train")
     # resolve through the planner registry: unknown --strategy fails fast
     # with the list of registered planners.
@@ -359,23 +406,13 @@ def train(args) -> dict:
                     "run_config": tuned.run_config}
     if dispatch:
         return _train_dispatch(args, cfg, run, (d_axis, cp))
-    strategy = effective_strategy(cfg, run.cp_strategy)
 
     topology, policy, monitor, injector, sim = _ft_setup(args, d_axis * cp,
                                                          cp)
     supervisor = ElasticSupervisor(topology, policy, data=d_axis, model=cp,
                                    monitor=monitor)
 
-    pipe_cfg = PipelineConfig(
-        dataset=args.dataset, context_len=args.seq_len,
-        batch_per_host=args.batch, cp_size=cp, strategy=strategy,
-        vocab_size=cfg.vocab_size, seed=run.seed,
-        buf_len=None if cp == 1 else None,
-        # pallas tables need block-divisible rank slices
-        align=128 if run.attention_impl == "pallas"
-        else (1 if cp == 1 else 16),
-        emit_tables=(run.attention_impl == "pallas" and cfg.uses_attention),
-        table_overlap=run.cp_overlap, table_grid=run.kernel_grid)
+    pipe_cfg = pipeline_config(args, cfg, run, cp)
 
     def build(mesh_, accum: int):
         bundle = build_train_step(cfg, mesh_, run, shape,
@@ -402,7 +439,7 @@ def train(args) -> dict:
                 init_params(jax.random.PRNGKey(run.seed), cfg), p_shard)
             state = {"params": params,
                      "opt": jax.device_put(adamw_init(params), o_shard)}
-    losses = []
+    losses, grad_norms = [], []
     it = Prefetcher(pipe_cfg, start_step=start) if args.prefetch else None
 
     def one_step(step: int) -> None:
@@ -421,6 +458,7 @@ def train(args) -> dict:
         state = {"params": p, "opt": o}
         loss = float(metrics["loss"])
         losses.append(loss)
+        grad_norms.append(float(metrics["grad_norm"]))
         # straggler loop: per-host wall times (inflated when simulated)
         # feed the speed EMAs; jitter tightens the planner's target
         # imbalance for subsequent batches (live on the non-prefetch path)
@@ -434,7 +472,7 @@ def train(args) -> dict:
         if step % args.log_every == 0:
             print(f"[train] step {step:5d} loss {loss:.4f} "
                   f"ce {float(metrics['ce']):.4f} "
-                  f"gnorm {float(metrics['grad_norm']):.2f} "
+                  f"gnorm {grad_norms[-1]:.2f} "
                   f"lr {float(metrics['lr']):.2e} "
                   f"imb {batch['stats']['imbalance']:.3f} "
                   f"comm_tok {batch['stats']['comm_tokens']} "
@@ -485,11 +523,11 @@ def train(args) -> dict:
     final = supervisor.run(one_step, start_step=start,
                            total_steps=args.steps, on_restore=on_restore)
     with set_mesh(cur["mesh"]):
-        ckpt.save(final, state, blocking=True)
+        save_s = _final_save(ckpt, final, state)
     if it:
         it.close()
-    return {"final_step": final, "losses": losses,
-            "recoveries": policy.restarts,
+    return {"final_step": final, "losses": losses, "grad_norms": grad_norms,
+            "ckpt_save_s": save_s, "recoveries": policy.restarts,
             "dead_hosts": sorted(supervisor.dead),
             "mesh": (cur["mesh"].shape["data"], cp),
             "accum": cur["accum"]}
@@ -500,6 +538,9 @@ def main():
     ap.add_argument("--arch", default="starcoder2_3b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-trainable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model to this many layers, widths "
+                         "unchanged (0 = the config's full depth)")
     ap.add_argument("--mesh", default="1x1", help="DxM or 'prod'")
     ap.add_argument("--strategy", default="flashcp")
     ap.add_argument("--attention-impl", default="xla")
@@ -507,6 +548,8 @@ def main():
     ap.add_argument("--seq-len", type=int, default=512)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--steps", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the weights and the data stream")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--q-chunk", type=int, default=128)
     ap.add_argument("--grad-compression", default="none")
@@ -547,6 +590,7 @@ def main():
                          "(0 = one host per data row)")
     ap.add_argument("--max-restarts", type=int, default=10)
     args = ap.parse_args()
+    setup_compile_cache()
     out = train(args)
     if out["losses"]:
         print(f"[train] done at step {out['final_step']}; "

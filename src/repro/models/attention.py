@@ -110,12 +110,12 @@ def attn_decode(p, cfg, x_t, pos_t, cache, *, impl: str = "flash",
 
     if impl == "flash":
         from repro.kernels.flash_decode import flash_decode_sharded
+        from repro.kernels.ops import resolve_interpret
 
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         out = flash_decode_sharded(
             q[:, :, 0, :], kc, vc, pos_t, shards=shards,
-            scale=hd ** -0.5, block_k=block_k, interpret=interpret)
+            scale=hd ** -0.5, block_k=block_k,
+            interpret=resolve_interpret(interpret))
         out = out.reshape(B, cfg.num_heads * hd).astype(x_t.dtype)
     elif impl == "dense":
         from repro.kernels.flash_decode import decode_reference
@@ -207,12 +207,11 @@ def attn_decode_paged(p, cfg, x_t, pos_t, cache, tables, active, *,
     lengths = jnp.where(active, pos_t, -1)
     if impl == "flash":
         from repro.kernels.flash_decode import flash_decode_paged
+        from repro.kernels.ops import resolve_interpret
 
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         out = flash_decode_paged(q[:, :, 0, :], kc, vc, lengths, tables,
                                  block_size=block_size, scale=hd ** -0.5,
-                                 interpret=interpret)
+                                 interpret=resolve_interpret(interpret))
     elif impl == "dense":
         from repro.kernels.flash_decode import paged_decode_reference
 

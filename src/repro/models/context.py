@@ -167,14 +167,17 @@ def local_selective_scan(dt, A, Bm, Cm, xf, reset, *, chunk: int = 64,
 
 def make_local_context(doc: jax.Array, pos: jax.Array,
                        attention_impl: str = "xla",
-                       interpret: bool = True,
+                       interpret: bool | None = None,
                        q_chunk: int = 512,
                        grid: str = "flat") -> ExecContext:
     """Single-device context: full-sequence doc-masked attention.
 
     ``grid`` picks the Pallas kernel schedule (flattened work queue by
-    default; ``"rect"`` for the rectangular baseline)."""
+    default; ``"rect"`` for the rectangular baseline); ``interpret=None``
+    runs the kernels in interpret mode only off-TPU."""
     from repro.kernels import ops as kops
+
+    interpret = kops.resolve_interpret(interpret)
 
     tabs_cache: list = []   # visit tables depend only on (doc, pos): built
     # once on first use instead of per attn call

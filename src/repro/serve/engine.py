@@ -144,7 +144,10 @@ class ServeEngine:
         self.layout = kv_layout
 
         if params is None:
-            params = init_params(jax.random.PRNGKey(seed), cfg)
+            # jitted: the f32 draws fuse into the stored-dtype weights
+            # instead of a full f32 copy of the model living first
+            params = jax.jit(init_params, static_argnums=1)(
+                jax.random.PRNGKey(seed), cfg)
         self.params = params
 
         self.block_size = block_size
