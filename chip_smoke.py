@@ -11,7 +11,9 @@ One chip:
   wlb_llm mix, mesh 1x1, Pallas attention on the flat grid with chunked
   overlap.  The train step is first compiled ahead of time: its
   ``memory_analysis`` must leave ``HEADROOM`` of the chip's memory free
-  and its HLO must hold the Pallas kernels (``tpu_custom_call``).  The
+  and its HLO must hold the three Pallas attention kernels as
+  ``tpu_custom_call`` instructions named ``doc_attn_fwd``,
+  ``doc_attn_dq`` and ``doc_attn_dkv``.  The
   loss must be finite and falling, and the first step's loss and grad
   norm must match the XLA attention path on the same params and batch
   within ``FIRST_STEP_RTOL``.
@@ -37,6 +39,7 @@ import gc
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import time
@@ -64,6 +67,8 @@ FIRST_STEP_RTOL = {"loss": 1e-2, "grad_norm": 2e-2}
 # keeps it near a tenth.  The smoke checks descent, not a schedule.
 LR = 5e-5
 CP_RTOL = 2e-2
+# the pallas_call names of kernels/doc_attention.py
+ATTN_KERNELS = ("doc_attn_fwd", "doc_attn_dq", "doc_attn_dkv")
 SERVE = dict(requests=4, prompt_len=1024, gen=32, prefill_chunk=512)
 SCRATCH = os.path.join(ROOT, ".smoke")
 
@@ -105,9 +110,11 @@ def peak_bytes() -> int:
 
 
 def assert_kernels_compiled(hlo: str, what: str) -> None:
-    check("tpu_custom_call" in hlo,
-          f"{what}: no tpu_custom_call in the compiled HLO -- the Pallas "
-          "kernels are not compiled in")
+    for name in ATTN_KERNELS:
+        check(re.search(rf'%{name}(\.\d+)? = [^\n]*custom_call_target='
+                        r'"tpu_custom_call"', hlo) is not None,
+              f"{what}: no tpu_custom_call named {name} in the compiled "
+              "HLO -- the Pallas attention kernels are not compiled in")
 
 
 def one_step(cfg, mesh, run, args, batch, *, want_hlo=False):

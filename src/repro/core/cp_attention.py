@@ -64,6 +64,8 @@ __all__ = ["make_cp_context", "resolve_overlap", "CP_AXIS",
 
 CP_AXIS = "model"
 NEG = -1e30
+#: jax.named_scope of the KV exchange's collectives (op_name in the HLO)
+EXCHANGE_SCOPE = "cp_exchange"
 
 
 def resolve_overlap(strategy: str, impl: str, overlap: str) -> str:
@@ -247,6 +249,15 @@ def _quantized_ppermute_bwd(axis_name, perm, _, g):
 _quantized_ppermute.defvjp(_quantized_ppermute_fwd, _quantized_ppermute_bwd)
 
 
+@jax.named_scope(EXCHANGE_SCOPE)
+def _gather_kv(k, v, kv_comm_dtype):
+    """All-gather of K and V over the CP axis (int8 on the wire if asked)."""
+    if kv_comm_dtype == "int8":
+        return _quantized_gather(k, CP_AXIS), _quantized_gather(v, CP_AXIS)
+    return (jax.lax.all_gather(k, CP_AXIS, axis=2, tiled=True),
+            jax.lax.all_gather(v, CP_AXIS, axis=2, tiled=True))
+
+
 def _wire_permute(x, perm, kv_comm_dtype):
     if kv_comm_dtype == "int8":
         return _quantized_ppermute(x, CP_AXIS, perm)
@@ -278,6 +289,7 @@ def _run_hops(init_part, payload, n_hops, attend, hop_xs=None,
     N = axis_size(CP_AXIS)
     perm = tuple((i, (i + 1) % N) for i in range(N))
 
+    @jax.named_scope(EXCHANGE_SCOPE)
     def fwd(kc, vc, dc, pc):
         return (_wire_permute(kc, perm, kv_comm_dtype),
                 _wire_permute(vc, perm, kv_comm_dtype),
@@ -328,12 +340,7 @@ def _flashcp_island(q, k, v, doc, pos, send_idx, gath_doc, gath_pos,
     sidx = send_idx[:, 0]                       # (b, buf)
     ksel = _take_tokens(k, sidx)
     vsel = _take_tokens(v, sidx)
-    if kv_comm_dtype == "int8":
-        kg = _quantized_gather(ksel, CP_AXIS)
-        vg = _quantized_gather(vsel, CP_AXIS)
-    else:
-        kg = jax.lax.all_gather(ksel, CP_AXIS, axis=2, tiled=True)
-        vg = jax.lax.all_gather(vsel, CP_AXIS, axis=2, tiled=True)
+    kg, vg = _gather_kv(ksel, vsel, kv_comm_dtype)
 
     # hide my own gathered segment (those tokens are already local KV)
     seg = jnp.arange(N * buf, dtype=jnp.int32) // buf
@@ -394,14 +401,10 @@ def _flashcp_island_chunked(q, k, v, doc, pos, send_idx, gath_doc, gath_pos,
 def _allgather_island(q, k, v, doc, pos, *, impl, q_chunk, interpret,
                       tables=None, block_q=128, block_k=128, grid="rect",
                       kv_comm_dtype="native"):
-    if kv_comm_dtype == "int8":
-        kg = _quantized_gather(k, CP_AXIS)
-        vg = _quantized_gather(v, CP_AXIS)
-    else:
-        kg = jax.lax.all_gather(k, CP_AXIS, axis=2, tiled=True)
-        vg = jax.lax.all_gather(v, CP_AXIS, axis=2, tiled=True)
-    gdoc = jax.lax.all_gather(doc, CP_AXIS, axis=1, tiled=True)
-    gpos = jax.lax.all_gather(pos, CP_AXIS, axis=1, tiled=True)
+    kg, vg = _gather_kv(k, v, kv_comm_dtype)
+    with jax.named_scope(EXCHANGE_SCOPE):
+        gdoc = jax.lax.all_gather(doc, CP_AXIS, axis=1, tiled=True)
+        gpos = jax.lax.all_gather(pos, CP_AXIS, axis=1, tiled=True)
     return _masked_attention(q, kg, vg, doc, pos, gdoc, gpos, impl=impl,
                              q_chunk=q_chunk, interpret=interpret,
                              tables=_unpack_rank_tables(tables),
@@ -450,10 +453,11 @@ def _ring_island(q, k, v, doc, pos, *, q_chunk, scale):
             q, kc, vc, doc, pos, dc, pc, impl="xla", scale=scale,
             q_chunk=q_chunk, interpret=False)
         acc, m, l = _merge_step((acc, m, l), part)
-        kc = jax.lax.ppermute(kc, CP_AXIS, perm)
-        vc = jax.lax.ppermute(vc, CP_AXIS, perm)
-        dc = jax.lax.ppermute(dc, CP_AXIS, perm)
-        pc = jax.lax.ppermute(pc, CP_AXIS, perm)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            kc = jax.lax.ppermute(kc, CP_AXIS, perm)
+            vc = jax.lax.ppermute(vc, CP_AXIS, perm)
+            dc = jax.lax.ppermute(dc, CP_AXIS, perm)
+            pc = jax.lax.ppermute(pc, CP_AXIS, perm)
         return (kc, vc, dc, pc, acc, m, l), None
 
     (kc, vc, dc, pc, acc, m, l), _ = jax.lax.scan(

@@ -166,15 +166,12 @@ def _emit_tables(cfg: PipelineConfig, stack: dict,
         block_q=cfg.table_block_q, block_k=cfg.table_block_k)
 
 
-def _batch_stats(encs, doc_lens_list, cache) -> dict:
+def _batch_stats(encs) -> dict:
     return {
         "comm_tokens": max(e.comm_tokens for e in encs),
         "buf_len": encs[0].buf_len,
         "t_loc": encs[0].t_loc,
         "imbalance": float(np.mean([e.imbalance for e in encs])),
-        "num_docs": float(np.mean([len(l) for l in doc_lens_list])),
-        "plan_cache_hit_rate":
-            cache.stats.hit_rate if cache is not None else 0.0,
     }
 
 
@@ -192,13 +189,12 @@ def make_batch(cfg: PipelineConfig, step: int, dp_rank: int = 0,
     tokens, labels = _synthesize_tokens(doc_lens_list, stack["perm"], rng,
                                         cfg.vocab_size)
 
-    _, _, cache = _planner_state(cfg)
     batch = {k: v for k, v in stack.items()}
     if cfg.emit_tables:
         batch.update(_emit_tables(cfg, stack, cfg.cp_size))
     batch["tokens"] = tokens
     batch["labels"] = labels
-    batch["stats"] = _batch_stats(encs, doc_lens_list, cache)
+    batch["stats"] = _batch_stats(encs)
     return batch
 
 
@@ -255,7 +251,6 @@ def make_dispatch_batch(cfg: PipelineConfig, dcfg, step: int,
     tokens, labels = _synthesize_tokens(dplan.rows, stack["perm"], row_rngs,
                                         cfg.vocab_size)
 
-    _, _, cache = _planner_state(gcfg)
     batch = {k: v for k, v in stack.items()}
     if cfg.emit_tables:
         batch.update(_emit_tables(cfg, stack, g))
@@ -264,7 +259,7 @@ def make_dispatch_batch(cfg: PipelineConfig, dcfg, step: int,
     batch["seq_tokens"] = np.asarray([int(r.sum()) for r in dplan.rows],
                                      np.int32)
     batch["group_id"] = dplan.group_of_row.astype(np.int32)
-    batch["stats"] = _batch_stats(encs, dplan.rows, cache)
+    batch["stats"] = _batch_stats(encs)
     batch["stats"]["dispatch"] = {**dplan.stats(),
                                   "profile": dplan.profile.as_dict()}
     return batch

@@ -58,7 +58,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.table_layout import GRID_TABLE_HALF
+from repro.table_layout import (FLAG_FIRST, FLAG_LAST, FLAG_VALID,
+                                GRID_TABLE_HALF)
 
 __all__ = [
     "BlockTables",
@@ -79,11 +80,6 @@ DEFAULT_BLOCK_K = 128
 NEG = -1e30  # finite -inf stand-in inside kernels (no nan from inf-inf)
 
 KIND_SKIP, KIND_PARTIAL, KIND_FULL = 0, 1, 2
-
-# work-queue step flags (build_work_queue / the grid="flat" kernels)
-FLAG_FIRST = 1   # first step of its block-row: reset the accumulators
-FLAG_LAST = 2    # last step of its block-row: finalize + write outputs
-FLAG_VALID = 4   # a real visit (unset on empty-row sentinels / pad tail)
 
 
 # ===================================================================== #
@@ -758,6 +754,7 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
             jax.ShapeDtypeStruct((B, Hq, Tq, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="doc_attn_fwd",
     )(*inputs, q, k, v, *_tile_meta(q_doc, q_pos, kv_doc, kv_pos))
     return out, lse[..., 0]
 
@@ -918,6 +915,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hq, Tq, D), q.dtype)],
         interpret=interpret,
+        name="doc_attn_dq",
     )(*inputs, q, k, v, do, _row_stat(lse), _row_stat(delta),
       *_tile_meta(q_doc, q_pos, kv_doc, kv_pos))
     return dq
@@ -1108,6 +1106,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
             jax.ShapeDtypeStruct((B, Hkv, Tk, D), v.dtype),
         ],
         interpret=interpret,
+        name="doc_attn_dkv",
     )(*inputs, q, k, v, do, _row_stat(lse), _row_stat(delta),
       *_tile_meta(q_doc, q_pos, kv_doc, kv_pos))
     return dk, dv

@@ -211,6 +211,7 @@ def flash_decode(q, k, v, lengths, *, scale=None,
         kernel, grid_spec=grid_spec,
         out_shape=out_shape if partial else out_shape[0],
         interpret=interpret,
+        name="flash_decode",
     )(lengths, q4, k, v)
     if not partial:
         return out.reshape(B, Hq, D)
@@ -337,5 +338,6 @@ def flash_decode_paged(q, k_pool, v_pool, lengths, tables,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(lengths, tables, q4, k4, v4)
     return out.reshape(B, Hq, D)

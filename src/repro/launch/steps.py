@@ -243,16 +243,18 @@ def build_train_step(cfg: ModelConfig, mesh, run: RunConfig,
             grads = jax.tree.map(lambda g: g / denom, g_sum)
             metrics = jax.tree.map(lambda v: v / denom, metr_sum)
             loss = loss_sum / denom
-        grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
-        if run.grad_compression != "none":
-            grads, _ = compress_tree(grads, jax.tree.map(
-                lambda g: jnp.zeros_like(g, jnp.float32), grads),
-                run.grad_compression)
-        lr = warmup_cosine(step, base_lr=run.lr,
-                           warmup_steps=run.warmup_steps,
-                           total_steps=run.total_steps)
-        params, opt_state = adamw_update(params, grads, opt_state, lr=lr,
-                                         weight_decay=run.weight_decay)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
+            if run.grad_compression != "none":
+                grads, _ = compress_tree(grads, jax.tree.map(
+                    lambda g: jnp.zeros_like(g, jnp.float32), grads),
+                    run.grad_compression)
+            lr = warmup_cosine(step, base_lr=run.lr,
+                               warmup_steps=run.warmup_steps,
+                               total_steps=run.total_steps)
+            params, opt_state = adamw_update(params, grads, opt_state,
+                                             lr=lr,
+                                             weight_decay=run.weight_decay)
         out_metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr,
                        "tokens": jnp.sum(batch["labels"] >= 0),
                        **metrics}

@@ -150,7 +150,8 @@ def _apply_sub(sub, cfg: ModelConfig, ctx: ExecContext, x, mixer: str,
                ffn: str):
     h = rmsnorm(sub["norm1"], x, cfg.norm_eps)
     if mixer == "attn":
-        mx = attn_apply(sub["attn"], cfg, ctx, h)
+        with jax.named_scope("attention"):
+            mx = attn_apply(sub["attn"], cfg, ctx, h)
     elif mixer == "mamba":
         mx = mamba_apply(sub["mamba"], h, ctx, d_state=cfg.mamba_d_state,
                          d_conv=cfg.mamba_d_conv)
@@ -167,14 +168,15 @@ def _apply_sub(sub, cfg: ModelConfig, ctx: ExecContext, x, mixer: str,
                                capacity_factor=cfg.capacity_factor,
                                kind=cfg.mlp)
         else:
-            f = mlp_apply(sub["mlp"], h, cfg.mlp)
+            with jax.named_scope("mlp"):
+                f = mlp_apply(sub["mlp"], h, cfg.mlp)
         x = x + f
     return x, aux
 
 
-def forward(params, cfg: ModelConfig, ctx: ExecContext, batch,
-            *, remat: bool = True):
-    """batch -> (logits (B, T, vocab), aux_loss scalar).
+def _trunk(params, cfg: ModelConfig, ctx: ExecContext, batch,
+           *, remat: bool = True):
+    """batch -> (final hidden states (B, T, d_model), aux_loss scalar).
 
     Remat policy: the residual stream between sublayers is saved; each
     sublayer's interior (attention logits, SSM scan operands, expert
@@ -206,19 +208,28 @@ def forward(params, cfg: ModelConfig, ctx: ExecContext, batch,
     (x, aux), _ = jax.lax.scan(period_body,
                                (x, jnp.zeros((), jnp.float32)),
                                params["layers"])
+    return x, aux
 
+
+def _logits(params, cfg: ModelConfig, x):
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if "lm_head" in params:
-        logits = dense(params["lm_head"], x)
-    else:
-        logits = x @ params["embed"]["e"].T.astype(x.dtype)
-    return logits, aux
+        return dense(params["lm_head"], x)
+    return x @ params["embed"]["e"].T.astype(x.dtype)
+
+
+def forward(params, cfg: ModelConfig, ctx: ExecContext, batch,
+            *, remat: bool = True):
+    """batch -> (logits (B, T, vocab), aux_loss scalar)."""
+    x, aux = _trunk(params, cfg, ctx, batch, remat=remat)
+    return _logits(params, cfg, x), aux
 
 
 def loss_fn(params, cfg: ModelConfig, ctx: ExecContext, batch,
             *, remat: bool = True):
-    logits, aux = forward(params, cfg, ctx, batch, remat=remat)
-    ce = cross_entropy(logits, batch["labels"])
+    x, aux = _trunk(params, cfg, ctx, batch, remat=remat)
+    with jax.named_scope("head_loss"):
+        ce = cross_entropy(_logits(params, cfg, x), batch["labels"])
     return ce + AUX_LOSS_WEIGHT * aux, {"ce": ce, "aux": aux}
 
 
@@ -227,32 +238,12 @@ def loss_fn_chunked_head(params, cfg: ModelConfig, ctx: ExecContext, batch,
     """Loss with the fused chunked head (local/unsharded execution only:
     under CP the token axis is mesh-sharded and the logits are already
     distributed — see EXPERIMENTS.md §Perf iteration 3)."""
-    kinds = block_kinds(cfg)
-    x = ctx.constrain(inputs_to_embeds(params, cfg, batch))
-
-    def sub(j):
-        mixer, ffn = kinds[j]
-
-        def apply(p, x):
-            y, a = _apply_sub(p, cfg, ctx, x, mixer, ffn)
-            return ctx.constrain(y), a
-        return jax.checkpoint(apply) if remat else apply
-
-    subs = [sub(j) for j in range(len(kinds))]
-
-    def body(carry, pp):
-        x, aux = carry
-        for j in range(len(kinds)):
-            x, a = subs[j](pp[f"sub_{j}"], x)
-            aux = aux + a
-        return (x, aux), None
-
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               params["layers"])
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = params["lm_head"]["w"] if "lm_head" in params \
-        else params["embed"]["e"].T
-    ce = chunked_lm_loss(x, head, batch["labels"], chunk=chunk)
+    x, aux = _trunk(params, cfg, ctx, batch, remat=remat)
+    with jax.named_scope("head_loss"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        head = params["lm_head"]["w"] if "lm_head" in params \
+            else params["embed"]["e"].T
+        ce = chunked_lm_loss(x, head, batch["labels"], chunk=chunk)
     return ce + AUX_LOSS_WEIGHT * aux, {"ce": ce, "aux": aux}
 
 

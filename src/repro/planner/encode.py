@@ -37,7 +37,8 @@ from collections import OrderedDict
 
 import numpy as np
 
-from repro.table_layout import FLAT_TABLE_NAMES
+from repro import obs
+from repro.table_layout import FLAG_VALID, FLAT_TABLE_NAMES
 
 from .plan import Shard, ShardArrays, ShardingPlan
 
@@ -223,6 +224,7 @@ def plan_shape_hints(plan: ShardingPlan, *, align: int = 1
     return t_loc, pick_buffer_bucket(max_send, t_loc)
 
 
+@obs.span("planner.encode")
 def encode_plan_batch(
     plans: list[ShardingPlan],
     *,
@@ -379,6 +381,7 @@ _TABLE_CACHE: OrderedDict[bytes, dict] = OrderedDict()
 _TABLE_CACHE_MAX = 8
 
 
+@obs.span("planner.tables")
 def emit_visit_tables(
     doc: np.ndarray,
     pos: np.ndarray,
@@ -426,6 +429,9 @@ def emit_visit_tables(
     ``"full"`` pads to the worst-case width of :func:`visit_table_shapes`
     for AOT-spec-exact shapes.  Results are memoized on the metadata
     content (PlanCache-hit batches re-emit for free).
+
+    The tables' work is counted into the ``planner.tables`` span
+    (:mod:`repro.obs`), on the memoized path too.
     """
     doc = np.ascontiguousarray(doc, np.int32)
     pos = np.ascontiguousarray(pos, np.int32)
@@ -449,6 +455,7 @@ def emit_visit_tables(
         hit = _TABLE_CACHE.get(key)
         if hit is not None:
             _TABLE_CACHE.move_to_end(key)
+            _count_tables(hit, doc)
             return dict(hit)
 
     B, C = doc.shape
@@ -518,7 +525,34 @@ def emit_visit_tables(
         _TABLE_CACHE[key] = dict(out)
         while len(_TABLE_CACHE) > _TABLE_CACHE_MAX:
             _TABLE_CACHE.popitem(last=False)
+    _count_tables(out, doc)
     return out
+
+
+def _count_tables(tables: dict, doc: np.ndarray) -> None:
+    """Count an emitted table set into the open span: ``tables.visits``
+    (valid visits), ``tables.slots`` (grid slots the padded tables launch)
+    and ``tables.pairs`` (doc-causal pairs, once for each direction of
+    each layout, since each direction covers every pair once)."""
+    visits = slots = 0
+    directions = set()                  # fq, rq (flat) | kv, q (rect)
+    for key, a in tables.items():
+        if key.endswith("_flags"):
+            visits += int(np.count_nonzero(a & FLAG_VALID))
+            slots += a.size
+        elif key.endswith("_nvis"):
+            visits += int(a.sum())
+            slots += tables[key[:-4] + "idx"].size
+        else:
+            continue
+        directions.add(key.split("_")[-2])
+    pairs = 0
+    for row in doc:
+        d = np.bincount(row[row >= 0]).astype(np.int64)
+        pairs += int(np.sum(d * (d + 1) // 2))
+    obs.count("tables.visits", visits)
+    obs.count("tables.slots", slots)
+    obs.count("tables.pairs", pairs * len(directions))
 
 
 def _group_shapes(prefix: str, lead: tuple, nq: int, nk: int,

@@ -13,6 +13,8 @@ import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Sequence
 
+from repro import obs
+
 __all__ = ["PlannerPool", "get_pool", "plan_many"]
 
 
@@ -62,6 +64,7 @@ def default_workers(batch: int) -> int:
     return max(1, min(int(batch), cpus - 1))
 
 
+@obs.span("planner.plan")
 def plan_many(plan_fn: Callable, lens_list: Sequence, *,
               workers: int = 0) -> list:
     """Plan every length mix in ``lens_list``; ``workers=0`` auto-sizes."""

@@ -17,6 +17,11 @@ the same keys back out of the plan arrays.
 
 from __future__ import annotations
 
+# work-queue step flags (build_work_queue / the grid="flat" kernels)
+FLAG_FIRST = 1   # first step of its block-row: reset the accumulators
+FLAG_LAST = 2    # last step of its block-row: finalize + write outputs
+FLAG_VALID = 4   # a real visit (unset on empty-row sentinels / pad tail)
+
 RECT_TABLE_NAMES = ("kv_idx", "kv_nvis", "q_idx", "q_nvis")
 FLAT_TABLE_NAMES = ("fq_row", "fq_col", "fq_flags",
                     "rq_row", "rq_col", "rq_flags")

@@ -4,7 +4,9 @@ Every kernel is lowered and compiled by the TPU compiler for a described
 (not attached) v5e chip at starcoder2_3b's attention layout: 24 query
 heads, 2 KV heads, head_dim 128, 128-token blocks, B = 2.  Nothing runs;
 a pass proves Mosaic accepts the kernel's block shapes, memory spaces and
-VMEM budget, which interpret-mode tests cannot show.
+VMEM budget, which interpret-mode tests cannot show.  Each kernel must
+also be a ``tpu_custom_call`` instruction under its stable ``pallas_call``
+name, which a profiler trace then shows as the operation's name.
 
 The topology is described inside a module-scoped fixture only, never at
 import: one process at a time may load the TPU library, and the test
@@ -13,6 +15,7 @@ workers must all collect the same tests.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,10 +49,13 @@ def _spec(chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
-def _compile(chip, fn, *shapes):
+def _compile(chip, name, fn, *shapes):
+    """Compile ``fn`` and check that the kernel ``name`` is in it as a
+    custom call named ``%<name>`` or ``%<name>.N``."""
     lowered = jax.jit(fn).lower(*(_spec(chip, s, d) for s, d in shapes))
     hlo = lowered.compile().as_text()
-    assert "tpu_custom_call" in hlo
+    assert re.search(rf'%{name}(\.\d+)? = [^\n]*custom_call_target='
+                     r'"tpu_custom_call"', hlo), f"no kernel named {name}"
     return hlo
 
 
@@ -77,7 +83,7 @@ def test_flash_fwd_compiles(chip, grid):
         return da.flash_fwd(q, k, v, qd, qp, kd, kp, tabs, scale=D ** -0.5,
                             block_q=BLK, block_k=BLK, grid=grid)
 
-    _compile(chip, fwd, *QKV, *META, *_tables(grid, STEPS))
+    _compile(chip, "doc_attn_fwd", fwd, *QKV, *META, *_tables(grid, STEPS))
 
 
 @pytest.mark.parametrize("grid", GRIDS)
@@ -87,7 +93,8 @@ def test_flash_bwd_dq_compiles(chip, grid):
                                tabs, scale=D ** -0.5, block_q=BLK,
                                block_k=BLK, grid=grid)
 
-    _compile(chip, dq, *QKV, QKV[0], *STATS, *META, *_tables(grid, STEPS))
+    _compile(chip, "doc_attn_dq", dq, *QKV, QKV[0], *STATS, *META,
+             *_tables(grid, STEPS))
 
 
 @pytest.mark.parametrize("grid", GRIDS)
@@ -97,14 +104,15 @@ def test_flash_bwd_dkv_compiles(chip, grid):
                                 tabs, scale=D ** -0.5, block_q=BLK,
                                 block_k=BLK, grid=grid)
 
-    _compile(chip, dkv, *QKV, QKV[0], *STATS, *META, *_tables(grid, STEPS))
+    _compile(chip, "doc_attn_dkv", dkv, *QKV, QKV[0], *STATS, *META,
+             *_tables(grid, STEPS))
 
 
 @pytest.mark.parametrize("partial", [False, True])
 def test_flash_decode_compiles(chip, partial):
     S = 4096
     fn = functools.partial(flash_decode, block_k=256, partial=partial)
-    _compile(chip, fn, ((8, HQ, D), jnp.bfloat16),
+    _compile(chip, "flash_decode", fn, ((8, HQ, D), jnp.bfloat16),
              ((8, HKV, S, D), jnp.bfloat16), ((8, HKV, S, D), jnp.bfloat16),
              ((8,), jnp.int32))
 
@@ -112,7 +120,7 @@ def test_flash_decode_compiles(chip, partial):
 def test_flash_decode_paged_compiles(chip):
     bs, blocks, per_req = 16, 2048, 256
     fn = functools.partial(flash_decode_paged, block_size=bs)
-    _compile(chip, fn, ((8, HQ, D), jnp.bfloat16),
+    _compile(chip, "flash_decode_paged", fn, ((8, HQ, D), jnp.bfloat16),
              ((HKV, blocks * bs, D), jnp.bfloat16),
              ((HKV, blocks * bs, D), jnp.bfloat16),
              ((8,), jnp.int32), ((8, per_req), jnp.int32))
