@@ -18,16 +18,36 @@ Two grid schedules walk those tables (``grid=`` on every kernel entry):
   revisiting pipeline) and are compute-gated by the per-row counts, but
   every padded slot still costs a grid step: on imbalanced document
   mixes the longest row's ``V`` taxes all ``B * nq`` rows.
-* ``grid="flat"`` — a **flattened 1D work queue**: the host emits the
-  CSR-style visit list itself, one grid step per *actual* visit, with
-  per-step ``(row, col)`` owner metadata and FIRST/LAST/VALID flags
-  marking block-row boundaries (``build_work_queue``).  Rows are sorted
-  by descending visit count (greedy LPT — long rows schedule first, so
-  a core-split grid stays balanced on skewed doc mixes), each row's
-  steps stay contiguous (the accumulator scratch carries one row at a
-  time), and zero-visit rows get a single sentinel step that writes
-  their zero output.  Padding waste is erased: total steps equal the
-  visit count (plus one sentinel per empty row and a pow2 tail bucket).
+* ``grid="flat"`` — a **flattened 1D work queue** ``(B, H, S)``: the
+  host emits the CSR-style visit list itself, one grid step per *actual*
+  visit, with per-step ``(row, col)`` owner metadata and
+  FIRST/LAST/VALID flags marking block-row boundaries
+  (``build_work_queue``).  Rows are sorted by descending visit count
+  (greedy LPT — long rows schedule first, so a core-split grid stays
+  balanced on skewed doc mixes), each row's steps stay contiguous (the
+  accumulator scratch carries one row at a time), and zero-visit rows
+  get a single sentinel step that writes their zero output.  Padding
+  waste is erased: total steps equal the visit count (plus one sentinel
+  per empty row and a pow2 tail bucket).
+
+**GQA head packing.**  ``H`` above is not the query-head count: one grid
+step covers one KV head and one visit, and computes it for ``hps`` query
+heads of that KV head's group at once (``_heads_per_step``: the largest
+divisor of the group whose step fits ``STEP_VMEM_BUDGET``; MHA gives 1).
+Query-side blocks (q, dO, out, dQ, lse/delta tiles) are ``(1, hps,
+block_q, ·)``, viewed inside the kernel as ``hps * block_q`` matmul rows;
+the K/V block and the doc mask (``_visible``, one ``(block_q, block_k)``
+tile broadcast over the heads) are fetched and built once per step.
+fwd and dQ run ``H = Hkv * group // hps`` head blocks.  dK/dV runs
+``(B, Hkv, nk, Vq, chunks)`` (rect) or ``(B, Hkv, S, chunks)`` (flat):
+``dV += Pᵀ·dO`` and ``dK += dSᵀ·Q`` contract over all packed rows in one
+matmul each, and the innermost ``chunks = group // hps`` axis (1 when
+the whole group fits) walks the rest of the group with init/finalize on
+its first/last index.  Per head every kernel computes what the one-head
+step computes; only the summation order over heads inside dK/dV
+differs.  Each entry point records ``attn.heads_per_step`` and
+``attn.grid_steps`` in a ``repro.obs`` span ``attn.kernel_build`` when
+it builds its ``pallas_call`` (at trace time, once per compile).
 
 Whole-doc placement ⇒ long contiguous visible ranges ⇒ few partial blocks
 and maximal MXU occupancy — exactly the paper's "kernel efficiency" axis,
@@ -51,6 +71,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +79,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.table_layout import (FLAG_FIRST, FLAG_LAST, FLAG_VALID,
                                 GRID_TABLE_HALF)
 
@@ -157,8 +179,9 @@ class BlockTables:
         return tuple(jnp.asarray(a) for a in self._flat())
 
     def grid_steps(self) -> dict[str, int]:
-        """Executed grid-step counts per (head, ) of both schedules — the
-        padding-waste accounting the kernel-efficiency bench reports."""
+        """Executed grid-step counts per head block of both schedules —
+        the padding-waste accounting the kernel-efficiency bench
+        reports."""
         B, nq, Vk = self.kv_idx.shape
         _, nk, Vq = self.q_idx.shape
         return {
@@ -522,6 +545,49 @@ def _build_block_tables_legacy(q_doc, q_pos, kv_doc, kv_pos, *, block_q,
 LANES = 128
 SUBLANES = 8
 
+#: VMEM one grid step may hold, as :func:`_step_vmem_bytes` counts it:
+#: the TPU compiler's default scoped-VMEM limit on v5e (16 MiB) less
+#: room for the compiler's own internal scratch.
+STEP_VMEM_BUDGET = 14 * 2**20
+
+
+def _step_vmem_bytes(hps: int, block_q: int, block_k: int,
+                     head_dim: int) -> int:
+    """VMEM one grid step holds with ``hps`` packed query heads: the
+    heaviest of the three kernels' double-buffered in/out blocks, scratch
+    and ``(hps * block_q, block_k)`` f32 temporaries, at 4 bytes an
+    element whatever the operands' dtype (so the count is the same for
+    bf16 and f32 inputs)."""
+    rows = hps * block_q
+    qd = 4 * rows * head_dim               # one packed (hps, bq, D) block
+    qs = 4 * rows * LANES                  # one packed lse/delta tile
+    tile = 4 * rows * block_k              # one (hps*bq, bk) temporary
+    kv = 4 * block_k * head_dim
+    meta = 4 * 2 * (block_q * LANES + SUBLANES * block_k)
+    fwd = 2 * (2 * qd + qs + 2 * kv + meta) + qd + 2 * qs + 4 * tile + qd
+    dq = 2 * (3 * qd + 2 * qs + 2 * kv + meta) + qd + 4 * tile + 2 * qd
+    dkv = 2 * (2 * qd + 2 * qs + 4 * kv + meta) + 2 * kv + 6 * tile + 2 * qd
+    return max(fwd, dq, dkv)
+
+
+def _heads_per_step(group: int, block_q: int, block_k: int,
+                    head_dim: int) -> int:
+    """Query heads one grid step carries: the largest divisor of the GQA
+    ``group`` whose step footprint fits :data:`STEP_VMEM_BUDGET`.  Group
+    1 (MHA) gives 1, a one-head step; 1 is the floor whatever the
+    budget."""
+    return max(h for h in range(1, group + 1) if group % h == 0 and (
+        h == 1 or _step_vmem_bytes(h, block_q, block_k, head_dim)
+        <= STEP_VMEM_BUDGET))
+
+
+def _record_build(hps: int, grid: tuple) -> None:
+    """Trace-time record of one kernel build: the packing width and the
+    grid steps it launches (once per compiled program, not per call)."""
+    with obs.span("attn.kernel_build"):
+        obs.count("attn.heads_per_step", hps)
+        obs.count("attn.grid_steps", math.prod(grid))
+
 
 def _tile_meta(q_doc, q_pos, kv_doc, kv_pos):
     """(B, T) int32 metadata -> q-side (B, Tq, LANES), kv-side
@@ -542,11 +608,23 @@ def _row_stat(x):
 
 
 def _visible(qd_ref, qp_ref, kd_ref, kp_ref):
+    """(bq, bk) doc-causal mask of the step's tile; one document layout
+    serves every packed head, so it is built once and broadcast."""
     qd = qd_ref[0, :, :1]                         # (bq, 1) column
     qp = qp_ref[0, :, :1]
     kd = kd_ref[0, :1, :]                         # (1, bk) row
     kp = kp_ref[0, :1, :]
     return (qd == kd) & (qp >= kp) & (qd >= 0) & (kd >= 0)
+
+
+def _rows(x):
+    """Packed heads as matmul rows: (hps, bq, X) -> (hps * bq, X)."""
+    return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+
+
+def _heads(x, hps):
+    """Matmul rows back to packed heads: (hps * bq, X) -> (hps, bq, X)."""
+    return x.reshape(hps, x.shape[0] // hps, x.shape[1])
 
 
 def _dot_f32(a, b):
@@ -566,32 +644,34 @@ def _fwd_init(acc_ref, m_ref, l_ref):
 
 def _fwd_visit(q_ref, k_ref, v_ref, qd_ref, qp_ref, kd_ref, kp_ref,
                acc_ref, m_ref, l_ref, scale):
-    q = q_ref[0, 0].astype(jnp.float32)
+    hps = q_ref.shape[1]
+    q = _rows(q_ref[0].astype(jnp.float32))                    # (hps*bq, D)
     k = k_ref[0, 0]
-    s = _dot_f32(q, k.T.astype(jnp.float32)) * scale          # (bq, bk) f32
+    s = _heads(_dot_f32(q, k.T.astype(jnp.float32)) * scale,
+               hps)                                            # (hps, bq, bk)
     vis = _visible(qd_ref, qp_ref, kd_ref, kp_ref)
     s = jnp.where(vis, s, NEG)
 
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_prev = m_ref[:, :, :1]
+    l_prev = l_ref[:, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)                            # NEG-NEG -> 1
     p = jnp.where(vis, jnp.exp(s - m_new), 0.0)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
     vv = v_ref[0, 0]
-    pv = _dot_f32(p.astype(vv.dtype), vv)
+    pv = _heads(_dot_f32(_rows(p).astype(vv.dtype), vv), hps)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
 def _fwd_finalize(out_ref, lse_ref, acc_ref, m_ref, l_ref):
-    l = l_ref[:, :1]
-    m = m_ref[:, :1]
+    l = l_ref[:, :, :1]
+    m = m_ref[:, :, :1]
     out = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30), 0.0)
-    out_ref[0, 0] = out.astype(out_ref.dtype)
+    out_ref[0] = out.astype(out_ref.dtype)
     lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), -jnp.inf)
-    lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+    lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def _fwd_kernel(kv_idx_ref, kv_nvis_ref,           # scalar prefetch
@@ -667,8 +747,12 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
     B, Hq, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
     group = Hq // Hkv
+    hps = _heads_per_step(group, block_q, block_k, D)
+    chunks = group // hps                 # head blocks per KV head
     nq = Tq // block_q
     tables = _check_grid(grid, tables)
+    q_blk = (1, hps, block_q, D)
+    stat_blk = (1, hps, block_q, LANES)
 
     if grid == "flat":
         row_t, col_t, flags_t = tables
@@ -678,7 +762,7 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
             return (b, h, row[b, s], 0)
 
         def kv_map(b, h, s, row, col, flags):
-            return (b, h // group, col[b, s], 0)
+            return (b, h // chunks, col[b, s], 0)
 
         def q_meta(b, h, s, row, col, flags):
             return (b, row[b, s], 0)
@@ -686,66 +770,49 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
         def kv_meta(b, h, s, row, col, flags):
             return (b, 0, col[b, s])
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, Hq, S),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_q, LANES),
-                             lambda b, h, s, row, col, flags:
-                             (b, h, row[b, s], 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, D), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-            ],
-        )
+        grid_shape = (B, Hkv * chunks, S)
         kernel = functools.partial(_fwd_kernel_flat, scale=scale)
         inputs = (row_t, col_t, flags_t)
     else:
         kv_idx, kv_nvis = tables
         V = kv_idx.shape[-1]
 
-        def kv_block(b, h, qi, vi, kv_idx, kv_nvis):
-            return (b, h // group, kv_idx[b, qi, vi], 0)
+        def q_map(b, h, qi, vi, *s):
+            return (b, h, qi, 0)
+
+        def kv_map(b, h, qi, vi, kv_idx, kv_nvis):
+            return (b, h // chunks, kv_idx[b, qi, vi], 0)
+
+        def q_meta(b, h, qi, vi, *s):
+            return (b, qi, 0)
 
         def kv_meta(b, h, qi, vi, kv_idx, kv_nvis):
             return (b, 0, kv_idx[b, qi, vi])
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hq, nq, V),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
-                pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, D), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, 128), jnp.float32),
-            ],
-        )
+        grid_shape = (B, Hkv * chunks, nq, V)
         kernel = functools.partial(_fwd_kernel, scale=scale, num_visits=V)
         inputs = (kv_idx, kv_nvis)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(inputs),
+        grid=grid_shape,
+        in_specs=[
+            pl.BlockSpec(q_blk, q_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, block_q, LANES), q_meta),
+            pl.BlockSpec((1, block_q, LANES), q_meta),
+            pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+            pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+        ],
+        out_specs=[pl.BlockSpec(q_blk, q_map),
+                   pl.BlockSpec(stat_blk, q_map)],
+        scratch_shapes=[
+            pltpu.VMEM((hps, block_q, D), jnp.float32),
+            pltpu.VMEM((hps, block_q, LANES), jnp.float32),
+            pltpu.VMEM((hps, block_q, LANES), jnp.float32),
+        ],
+    )
+    _record_build(hps, grid_shape)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -764,20 +831,21 @@ def flash_fwd(q, k, v, q_doc, q_pos, kv_doc, kv_pos,
 # ===================================================================== #
 def _dq_visit(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
               qd_ref, qp_ref, kd_ref, kp_ref, dq_acc, scale):
-    q = q_ref[0, 0].astype(jnp.float32)
+    hps = q_ref.shape[1]
+    q = _rows(q_ref[0].astype(jnp.float32))
     k = k_ref[0, 0]
     v = v_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, :1]                        # (bq, 1)
+    do = _rows(do_ref[0].astype(jnp.float32))
+    lse = lse_ref[0, :, :, :1]                        # (hps, bq, 1)
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    delta = dl_ref[0, 0, :, :1]
+    delta = dl_ref[0, :, :, :1]
 
-    s = _dot_f32(q, k.T.astype(jnp.float32)) * scale
+    s = _heads(_dot_f32(q, k.T.astype(jnp.float32)) * scale, hps)
     vis = _visible(qd_ref, qp_ref, kd_ref, kp_ref)
     p = jnp.where(vis, jnp.exp(s - lse_safe), 0.0)
-    dp = _dot_f32(do, v.T.astype(jnp.float32))
+    dp = _heads(_dot_f32(do, v.T.astype(jnp.float32)), hps)
     ds = p * (dp - delta) * scale
-    dq_acc[...] += _dot_f32(ds.astype(k.dtype), k)
+    dq_acc[...] += _heads(_dot_f32(_rows(ds).astype(k.dtype), k), hps)
 
 
 def _dq_kernel(kv_idx_ref, kv_nvis_ref,
@@ -799,7 +867,7 @@ def _dq_kernel(kv_idx_ref, kv_nvis_ref,
 
     @pl.when(vi == num_visits - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dq_kernel_flat(row_ref, col_ref, flags_ref,
@@ -822,7 +890,7 @@ def _dq_kernel_flat(row_ref, col_ref, flags_ref,
 
     @pl.when((flags & FLAG_LAST) != 0)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
@@ -835,8 +903,12 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
     B, Hq, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
     group = Hq // Hkv
+    hps = _heads_per_step(group, block_q, block_k, D)
+    chunks = group // hps
     nq = Tq // block_q
     tables = _check_grid(grid, tables)
+    q_blk = (1, hps, block_q, D)
+    stat_blk = (1, hps, block_q, LANES)
 
     if grid == "flat":
         row_t, col_t, flags_t = tables
@@ -845,11 +917,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
         def q_map(b, h, s, row, col, flags):
             return (b, h, row[b, s], 0)
 
-        def q_vec(b, h, s, row, col, flags):
-            return (b, h, row[b, s], 0)
-
         def kv_map(b, h, s, row, col, flags):
-            return (b, h // group, col[b, s], 0)
+            return (b, h // chunks, col[b, s], 0)
 
         def q_meta(b, h, s, row, col, flags):
             return (b, row[b, s], 0)
@@ -857,59 +926,47 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
         def kv_meta(b, h, s, row, col, flags):
             return (b, 0, col[b, s])
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, Hq, S),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
-                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-            ],
-            out_specs=[pl.BlockSpec((1, 1, block_q, D), q_map)],
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        )
+        grid_shape = (B, Hkv * chunks, S)
         kernel = functools.partial(_dq_kernel_flat, scale=scale)
         inputs = (row_t, col_t, flags_t)
     else:
         kv_idx, kv_nvis = tables
         V = kv_idx.shape[-1]
 
-        def kv_block(b, h, qi, vi, kv_idx, kv_nvis):
-            return (b, h // group, kv_idx[b, qi, vi], 0)
+        def q_map(b, h, qi, vi, *s):
+            return (b, h, qi, 0)
+
+        def kv_map(b, h, qi, vi, kv_idx, kv_nvis):
+            return (b, h // chunks, kv_idx[b, qi, vi], 0)
+
+        def q_meta(b, h, qi, vi, *s):
+            return (b, qi, 0)
 
         def kv_meta(b, h, qi, vi, kv_idx, kv_nvis):
             return (b, 0, kv_idx[b, qi, vi])
 
-        def q_block(b, h, qi, vi, *s):
-            return (b, h, qi, 0)
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hq, nq, V),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
-                pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, vi, *s: (b, h, qi, 0)),
-                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, LANES), lambda b, h, qi, vi, *s: (b, qi, 0)),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-            ],
-            out_specs=[pl.BlockSpec((1, 1, block_q, D), q_block)],
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        )
+        grid_shape = (B, Hkv * chunks, nq, V)
         kernel = functools.partial(_dq_kernel, scale=scale, num_visits=V)
         inputs = (kv_idx, kv_nvis)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(inputs),
+        grid=grid_shape,
+        in_specs=[
+            pl.BlockSpec(q_blk, q_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec(q_blk, q_map),
+            pl.BlockSpec(stat_blk, q_map),
+            pl.BlockSpec(stat_blk, q_map),
+            pl.BlockSpec((1, block_q, LANES), q_meta),
+            pl.BlockSpec((1, block_q, LANES), q_meta),
+            pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+            pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+        ],
+        out_specs=[pl.BlockSpec(q_blk, q_map)],
+        scratch_shapes=[pltpu.VMEM((hps, block_q, D), jnp.float32)],
+    )
+    _record_build(hps, grid_shape)
     (dq,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -922,25 +979,28 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
 
 
 # ===================================================================== #
-# backward: dK, dV  (kv-block rows x GQA group; rect grid or flat queue)
+# backward: dK, dV  (kv-block rows x head chunks; rect grid or flat queue)
 # ===================================================================== #
 def _dkv_visit(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                qd_ref, qp_ref, kd_ref, kp_ref, dk_acc, dv_acc, scale):
-    q = q_ref[0, 0].astype(jnp.float32)
+    hps = q_ref.shape[1]
+    q = _rows(q_ref[0].astype(jnp.float32))
     k = k_ref[0, 0]
     v = v_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, :1]
+    do = _rows(do_ref[0].astype(jnp.float32))
+    lse = lse_ref[0, :, :, :1]
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    delta = dl_ref[0, 0, :, :1]
+    delta = dl_ref[0, :, :, :1]
 
-    s = _dot_f32(q, k.T.astype(jnp.float32)) * scale    # (bq, bk)
+    s = _heads(_dot_f32(q, k.T.astype(jnp.float32)) * scale,
+               hps)                                        # (hps, bq, bk)
     vis = _visible(qd_ref, qp_ref, kd_ref, kp_ref)
     p = jnp.where(vis, jnp.exp(s - lse_safe), 0.0)
-    dv_acc[...] += _dot_f32(p.T.astype(do.dtype), do)
-    dp = _dot_f32(do, v.T.astype(jnp.float32))
+    # one matmul contracts over every packed head's rows
+    dv_acc[...] += _dot_f32(_rows(p).T.astype(do.dtype), do)
+    dp = _heads(_dot_f32(do, v.T.astype(jnp.float32)), hps)
     ds = p * (dp - delta) * scale
-    dk_acc[...] += _dot_f32(ds.T.astype(q.dtype), q)
+    dk_acc[...] += _dot_f32(_rows(ds).T.astype(q.dtype), q)
 
 
 def _dkv_kernel(q_idx_ref, q_nvis_ref,
@@ -948,7 +1008,7 @@ def _dkv_kernel(q_idx_ref, q_nvis_ref,
                 qd_ref, qp_ref, kd_ref, kp_ref,
                 dk_ref, dv_ref,
                 dk_acc, dv_acc,
-                *, scale: float, num_visits: int, group: int):
+                *, scale: float, num_visits: int, chunks: int):
     b, _, ki, vqi, gi = (pl.program_id(i) for i in range(5))
 
     @pl.when((vqi == 0) & (gi == 0))
@@ -961,7 +1021,7 @@ def _dkv_kernel(q_idx_ref, q_nvis_ref,
         _dkv_visit(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                    qd_ref, qp_ref, kd_ref, kp_ref, dk_acc, dv_acc, scale)
 
-    @pl.when((vqi == num_visits - 1) & (gi == group - 1))
+    @pl.when((vqi == num_visits - 1) & (gi == chunks - 1))
     def _finalize():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
@@ -972,7 +1032,7 @@ def _dkv_kernel_flat(row_ref, col_ref, flags_ref,
                      qd_ref, qp_ref, kd_ref, kp_ref,
                      dk_ref, dv_ref,
                      dk_acc, dv_acc,
-                     *, scale: float, group: int):
+                     *, scale: float, chunks: int):
     b, _, s, gi = (pl.program_id(i) for i in range(4))
     flags = flags_ref[b, s]
 
@@ -986,7 +1046,7 @@ def _dkv_kernel_flat(row_ref, col_ref, flags_ref,
         _dkv_visit(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                    qd_ref, qp_ref, kd_ref, kp_ref, dk_acc, dv_acc, scale)
 
-    @pl.when(((flags & FLAG_LAST) != 0) & (gi == group - 1))
+    @pl.when(((flags & FLAG_LAST) != 0) & (gi == chunks - 1))
     def _finalize():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
@@ -1003,101 +1063,78 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_doc, q_pos, kv_doc, kv_pos,
     B, Hq, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
     group = Hq // Hkv
+    hps = _heads_per_step(group, block_q, block_k, D)
+    chunks = group // hps                 # innermost grid axis
     nk = Tk // block_k
     tables = _check_grid(grid, tables)
+    q_blk = (1, hps, block_q, D)
+    stat_blk = (1, hps, block_q, LANES)
 
     if grid == "flat":
         row_t, col_t, flags_t = tables
         S = row_t.shape[-1]
 
-        def q_block(b, hkv, s, gi, row, col, flags):
-            return (b, hkv * group + gi, col[b, s], 0)
-
-        def q_vec(b, hkv, s, gi, row, col, flags):
-            return (b, hkv * group + gi, col[b, s], 0)
+        def q_map(b, hkv, s, gi, row, col, flags):
+            return (b, hkv * chunks + gi, col[b, s], 0)
 
         def q_meta(b, hkv, s, gi, row, col, flags):
             return (b, col[b, s], 0)
 
-        def kv_block(b, hkv, s, gi, row, col, flags):
+        def kv_map(b, hkv, s, gi, row, col, flags):
             return (b, hkv, row[b, s], 0)
 
         def kv_meta(b, hkv, s, gi, row, col, flags):
             return (b, 0, row[b, s])
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, Hkv, S, group),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
-                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-            ],
-        )
+        grid_shape = (B, Hkv, S, chunks)
         kernel = functools.partial(_dkv_kernel_flat, scale=scale,
-                                   group=group)
+                                   chunks=chunks)
         inputs = (row_t, col_t, flags_t)
     else:
         q_idx, q_nvis = tables
         Vq = q_idx.shape[-1]
 
-        def q_block(b, hkv, ki, vqi, gi, q_idx, q_nvis):
-            return (b, hkv * group + gi, q_idx[b, ki, vqi], 0)
-
-        def q_vec(b, hkv, ki, vqi, gi, q_idx, q_nvis):
-            return (b, hkv * group + gi, q_idx[b, ki, vqi], 0)
+        def q_map(b, hkv, ki, vqi, gi, q_idx, q_nvis):
+            return (b, hkv * chunks + gi, q_idx[b, ki, vqi], 0)
 
         def q_meta(b, hkv, ki, vqi, gi, q_idx, q_nvis):
             return (b, q_idx[b, ki, vqi], 0)
 
-        def kv_block(b, hkv, ki, vqi, gi, *s):
+        def kv_map(b, hkv, ki, vqi, gi, *s):
             return (b, hkv, ki, 0)
 
         def kv_meta(b, hkv, ki, vqi, gi, *s):
             return (b, 0, ki)
 
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hkv, nk, Vq, group),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_q, D), q_block),
-                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
-                pl.BlockSpec((1, 1, block_q, LANES), q_vec),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, block_q, LANES), q_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-                pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-                pl.BlockSpec((1, 1, block_k, D), kv_block),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-            ],
-        )
+        grid_shape = (B, Hkv, nk, Vq, chunks)
         kernel = functools.partial(_dkv_kernel, scale=scale, num_visits=Vq,
-                                   group=group)
+                                   chunks=chunks)
         inputs = (q_idx, q_nvis)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(inputs),
+        grid=grid_shape,
+        in_specs=[
+            pl.BlockSpec(q_blk, q_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec(q_blk, q_map),
+            pl.BlockSpec(stat_blk, q_map),
+            pl.BlockSpec(stat_blk, q_map),
+            pl.BlockSpec((1, block_q, LANES), q_meta),
+            pl.BlockSpec((1, block_q, LANES), q_meta),
+            pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+            pl.BlockSpec((1, SUBLANES, block_k), kv_meta),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+        ],
+    )
+    _record_build(hps, grid_shape)
     dk, dv = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -13,9 +13,9 @@ Where JAX is imported, each span is also a
 ``jax.profiler.TraceAnnotation``: in a profiler trace it shows on the host
 plane under its name, on the clock of the device's operations.
 
-Recording is on by default.  Spans are per batch on host threads, never
-per token and never inside jitted code; ``set_enabled(False)`` turns
-recording off.
+Recording is on by default.  Spans are per batch on host threads, or
+per kernel build while JAX traces, never per token and never in compiled
+code; ``set_enabled(False)`` turns recording off.
 
 The program's spans and counters:
 
@@ -25,7 +25,12 @@ The program's spans and counters:
   ``tables.visits`` (valid kernel visits), ``tables.slots`` (grid slots
   launched: the padded tables' size) and ``tables.pairs`` (doc-causal
   query-key pairs the tables cover), each summed over every table
-  returned: both directions, local and hops, every sample and rank.
+  returned: both directions, local and hops, every sample and rank;
+* ``attn.kernel_build`` -- one per ``pallas_call`` that
+  ``kernels.doc_attention``'s fwd, dQ and dK/dV entry points build (at
+  trace time, so once per compiled program), with the counters
+  ``attn.heads_per_step`` (query heads one grid step carries) and
+  ``attn.grid_steps`` (the product of the kernel's grid).
 """
 
 from __future__ import annotations

@@ -1,6 +1,9 @@
 """Pallas kernel validation: interpret-mode allclose vs the jnp oracle
-across shape/dtype sweeps (fwd, dq, dkv), plus block-table soundness
-properties (hypothesis)."""
+across shape/dtype sweeps (fwd, dq, dkv) on both grid schedules, the
+GQA head-packing width and its trace-time record, plus block-table
+soundness properties (hypothesis)."""
+
+import time
 
 import numpy as np
 import jax
@@ -9,6 +12,8 @@ import pytest
 pytest.importorskip("hypothesis", reason="property tests need the hypothesis dev dependency")
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
+from repro.kernels import doc_attention as da
 from repro.kernels.doc_attention import build_block_tables
 from repro.kernels.ops import doc_attention_xla, doc_flash_attention
 from repro.kernels.ref import doc_mask, mha_reference
@@ -48,7 +53,10 @@ CASES = [
     (2, 2, 2, 64, 64, 8, 32, 16, 5, np.float32, 2e-5),
     (1, 4, 4, 64, 128, 64, 64, 64, 2, np.float32, 2e-5),
     (2, 4, 2, 64, 128, 16, 16, 16, 4, jnp.bfloat16, 3e-2),
+    # starcoder2_3b's attention widths: one step carries a 12-head group
+    (1, 24, 2, 256, 256, 128, 128, 128, 3, np.float32, 2e-5),
 ]
+GRIDS = ("rect", "flat")
 
 
 @pytest.mark.parametrize("B,Hq,Hkv,Tq,Tk,D,bq,bk,docs,dtype,tol", CASES)
@@ -57,30 +65,97 @@ def test_fwd_matches_oracle(B, Hq, Hkv, Tq, Tk, D, bq, bk, docs, dtype, tol):
     q, k, v = _tensors(B, Hq, Hkv, Tq, Tk, D, dtype)
     tabs = build_block_tables(qd, qp, kd, kp, block_q=bq, block_k=bk)
     ref = mha_reference(q, k, v, *map(jnp.asarray, (qd, qp, kd, kp)))
-    out = doc_flash_attention(q, k, v, *map(jnp.asarray, (qd, qp, kd, kp)),
-                              tabs, interpret=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               atol=tol, rtol=tol)
+    for grid in GRIDS:
+        out = doc_flash_attention(q, k, v,
+                                  *map(jnp.asarray, (qd, qp, kd, kp)),
+                                  tabs, grid=grid, interpret=True)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol, err_msg=grid)
 
 
 @pytest.mark.parametrize("B,Hq,Hkv,Tq,Tk,D,bq,bk,docs,dtype,tol",
-                         CASES[:3])
+                         CASES[:3] + CASES[-1:])
 def test_bwd_matches_oracle(B, Hq, Hkv, Tq, Tk, D, bq, bk, docs, dtype, tol):
     qd, qp, kd, kp = _layout(B, Tq, Tk, docs, q_pad=2)
     q, k, v = _tensors(B, Hq, Hkv, Tq, Tk, D, dtype)
     tabs = build_block_tables(qd, qp, kd, kp, block_q=bq, block_k=bk)
     jqd, jqp, jkd, jkp = map(jnp.asarray, (qd, qp, kd, kp))
 
-    g_pl = jax.grad(lambda *a: jnp.sum(doc_flash_attention(
-        *a, jqd, jqp, jkd, jkp, tabs, interpret=True) ** 2), (0, 1, 2))(
-            q, k, v)
     g_rf = jax.grad(lambda *a: jnp.sum(mha_reference(
         *a, jqd, jqp, jkd, jkp) ** 2), (0, 1, 2))(q, k, v)
-    for a, b, nm in zip(g_pl, g_rf, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=25 * tol, rtol=25 * tol,
-                                   err_msg=f"d{nm}")
+    for grid in GRIDS:
+        g_pl = jax.grad(lambda *a: jnp.sum(doc_flash_attention(
+            *a, jqd, jqp, jkd, jkp, tabs, grid=grid,
+            interpret=True) ** 2), (0, 1, 2))(q, k, v)
+        for a, b, nm in zip(g_pl, g_rf, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=25 * tol, rtol=25 * tol,
+                                       err_msg=f"{grid} d{nm}")
+
+
+# --------------------------------------------------------------------- #
+# GQA head packing: how many query heads one grid step carries
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("group,D", [(1, 128), (12, 128), (48, 128),
+                                     (9, 128), (8, 128), (6, 128), (4, 128),
+                                     (7, 64)])
+def test_heads_per_step_is_largest_fitting_divisor(group, D):
+    hps = da._heads_per_step(group, 128, 128, D)
+    assert group % hps == 0
+    fits = [h for h in range(1, group + 1) if group % h == 0
+            and da._step_vmem_bytes(h, 128, 128, D) <= da.STEP_VMEM_BUDGET]
+    assert hps == max(fits, default=1)
+
+
+def test_heads_per_step_at_published_groups(monkeypatch):
+    # MHA keeps the one-head step; starcoder2_3b packs its whole group
+    assert da._heads_per_step(1, 128, 128, 128) == 1
+    assert da._heads_per_step(12, 128, 128, 128) == 12
+    # granite_34b's 48-head group does not fit one step: a divisor does
+    hps = da._heads_per_step(48, 128, 128, 128)
+    assert 1 < hps < 48 and 48 % hps == 0
+    assert da._step_vmem_bytes(hps, 128, 128, 128) <= da.STEP_VMEM_BUDGET
+    # a smaller budget packs fewer heads; one nothing fits leaves one
+    # head a step, never zero
+    monkeypatch.setattr(da, "STEP_VMEM_BUDGET",
+                        da._step_vmem_bytes(4, 128, 128, 128))
+    assert da._heads_per_step(12, 128, 128, 128) == 4
+    monkeypatch.setattr(da, "STEP_VMEM_BUDGET", 1)
+    assert da._heads_per_step(12, 128, 128, 128) == 1
+
+
+@pytest.mark.parametrize("grid", GRIDS)
+def test_kernel_builds_record_packing(grid):
+    """Tracing the three kernels at starcoder2_3b's widths records, per
+    build, 12 heads a step and a grid of one step per KV head and slot."""
+    B, Hq, Hkv, T, D, blk = 2, 24, 2, 1024, 128, 128
+    nb, S = T // blk, 20
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    sd = jax.ShapeDtypeStruct
+    q, kv = sd((B, Hq, T, D), bf16), sd((B, Hkv, T, D), bf16)
+    meta = [sd((B, T), i32)] * 4
+    stat = sd((B, Hq, T), f32)
+    if grid == "rect":
+        fwd_t = (sd((B, nb, 5), i32), sd((B, nb), i32))
+        rev_t = (sd((B, nb, 6), i32), sd((B, nb), i32))
+        want = [B * Hkv * nb * 5, B * Hkv * nb * 5, B * Hkv * nb * 6]
+    else:
+        fwd_t = (sd((B, S), i32),) * 3
+        rev_t = (sd((B, S + 3), i32),) * 3
+        want = [B * Hkv * S, B * Hkv * S, B * Hkv * (S + 3)]
+    kw = dict(scale=D ** -0.5, block_q=blk, block_k=blk, grid=grid)
+    t0 = time.perf_counter_ns()
+    jax.eval_shape(lambda *a: da.flash_fwd(*a[:7], a[7:], **kw),
+                   q, kv, kv, *meta, *fwd_t)
+    jax.eval_shape(lambda *a: da.flash_bwd_dq(*a[:10], a[10:], **kw),
+                   q, kv, kv, q, stat, stat, *meta, *fwd_t)
+    jax.eval_shape(lambda *a: da.flash_bwd_dkv(*a[:10], a[10:], **kw),
+                   q, kv, kv, q, stat, stat, *meta, *rev_t)
+    builds = [r.counts for r in obs.records(since_ns=t0)
+              if r.name == "attn.kernel_build"]
+    assert [c["attn.heads_per_step"] for c in builds] == [12, 12, 12]
+    assert [c["attn.grid_steps"] for c in builds] == want
 
 
 def test_xla_path_matches_oracle():

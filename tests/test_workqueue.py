@@ -4,8 +4,9 @@ Properties of :func:`build_work_queue` — the queue is an exact
 permutation of the rectangular visit set with contiguous LPT-ordered
 rows and correct FIRST/LAST/VALID boundary flags — plus interpret-mode
 fwd+grad parity of the flat vs rect kernel schedules across GQA group
-sizes and across the CP table emission (per-rank concat layouts and
-chunked hop tables for CP in {2, 4}).
+sizes (with whole and chunked head packing) and across the CP table
+emission (per-rank concat layouts and chunked hop tables for CP in
+{2, 4}).
 """
 
 import numpy as np
@@ -19,6 +20,7 @@ try:
 except ImportError:      # parity tests below run regardless
     HAVE_HYPOTHESIS = False
 
+from repro.kernels import doc_attention as da
 from repro.kernels.doc_attention import (FLAG_FIRST, FLAG_LAST, FLAG_VALID,
                                          build_block_tables,
                                          build_work_queue)
@@ -147,10 +149,9 @@ def _tensors(B, Hq, Hkv, Tq, Tk, D, seed=1):
     return map(jnp.asarray, (q, k, v))
 
 
-@pytest.mark.parametrize("Hq,Hkv", [(4, 4), (4, 2), (4, 1)])
-def test_flat_matches_rect_fwd_and_grad_gqa(Hq, Hkv):
-    """Flat and rect schedules agree (and match the oracle) for MHA,
-    GQA and MQA group sizes, values and gradients."""
+def _flat_rect_parity(Hq, Hkv):
+    """Flat and rect schedules agree with each other and with the oracle,
+    values and gradients."""
     B, Tq, Tk, D, bq, bk = 2, 64, 128, 16, 16, 16
     qd, qp, kd, kp = _layout(B, Tq, Tk, 4, q_pad=3, kv_pad=5)
     q, k, v = _tensors(B, Hq, Hkv, Tq, Tk, D)
@@ -180,10 +181,10 @@ def test_flat_matches_rect_fwd_and_grad_gqa(Hq, Hkv):
                                    err_msg=f"d{nm}")
 
 
-def test_flat_partial_mode_matches_rect():
+def _partial_parity(Hq, Hkv):
     """The (o, lse) partial form — the CP merge substrate input — agrees
     across schedules, including the dlse backward fold."""
-    B, Hq, Hkv, T, D = 1, 4, 2, 64, 16
+    B, T, D = 1, 64, 16
     qd, qp, kd, kp = _layout(B, T, T, 3, q_pad=4)
     q, k, v = _tensors(B, Hq, Hkv, T, T, D)
     tabs = build_block_tables(qd, qp, kd, kp, block_q=16, block_k=16)
@@ -204,6 +205,40 @@ def test_flat_partial_mode_matches_rect():
     for a, b, nm in zip(gf, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4, err_msg=f"d{nm}")
+
+
+def _cap_heads_per_step(monkeypatch, group, hps):
+    """Shrink the step budget so that the kernels pack ``hps`` of the
+    group's heads a step and walk the rest on the head-chunk axis."""
+    monkeypatch.setattr(da, "STEP_VMEM_BUDGET",
+                        da._step_vmem_bytes(hps, 16, 16, 16))
+    assert da._heads_per_step(group, 16, 16, 16) == hps
+
+
+@pytest.mark.parametrize("Hq,Hkv", [(4, 4), (4, 2), (4, 1), (24, 2)])
+def test_flat_matches_rect_fwd_and_grad_gqa(Hq, Hkv):
+    """Flat and rect schedules agree (and match the oracle) for MHA,
+    GQA and MQA group sizes, up to starcoder2_3b's group of 12."""
+    _flat_rect_parity(Hq, Hkv)
+
+
+@pytest.mark.parametrize("Hq,Hkv,hps", [(24, 2, 4), (12, 1, 3)])
+def test_flat_matches_rect_with_head_chunks(monkeypatch, Hq, Hkv, hps):
+    """Fewer heads a step than the group: the residual head-chunk axis
+    accumulates dK/dV across chunks on both schedules."""
+    _cap_heads_per_step(monkeypatch, Hq // Hkv, hps)
+    _flat_rect_parity(Hq, Hkv)
+
+
+def test_flat_partial_mode_matches_rect():
+    _partial_parity(4, 2)
+
+
+@pytest.mark.parametrize("Hq,Hkv,hps", [(24, 2, 12), (24, 2, 4)])
+def test_flat_partial_mode_matches_rect_packed(monkeypatch, Hq, Hkv, hps):
+    """The partial form at starcoder2_3b's group, whole and in chunks."""
+    _cap_heads_per_step(monkeypatch, Hq // Hkv, hps)
+    _partial_parity(Hq, Hkv)
 
 
 # --------------------------------------------------------------------- #
