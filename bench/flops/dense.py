@@ -8,9 +8,11 @@ Model FLOPs of one step (no recomputation counted):
   two MLP matrices, and the LM head (a matmul with the embedding's
   transpose where the two are tied); the embedding lookup is left out;
 * ``12 * layers * heads * head_dim * pairs`` for document-causal attention,
-  where ``pairs = sum over documents of d (d + 1) / 2``: 4 per pair and
-  head dimension in the forward pass (QK^T and PV), 8 in the backward
-  (dV, dP, dQ, dK).
+  where ``pairs`` is the sum over sequences of :func:`attention_pairs`,
+  the query-key pairs the family's attention computes (for dense,
+  document-causal attention over whole documents: ``d (d + 1) / 2`` per
+  document): 4 per pair and head dimension in the forward pass (QK^T and
+  PV), 8 in the backward (dV, dP, dQ, dK).
 
 Attention bytes of one layer, the least any kernel must move: Q, O, dO and
 dQ (``T * heads * head_dim`` each) and K, V, dK and dV
@@ -20,8 +22,10 @@ float32 row statistics (log-sum-exp and delta, ``T * heads`` each).
 
 from __future__ import annotations
 
-__all__ = ["matmul_params", "model_flops", "attention_flops",
-           "attention_bytes"]
+from harness.traffic import causal_pairs
+
+__all__ = ["attention_pairs", "matmul_params", "model_flops",
+           "attention_flops", "attention_bytes"]
 
 
 def _dims(cfg: dict):
@@ -30,6 +34,19 @@ def _dims(cfg: dict):
     kv = cfg["num_key_value_heads"]
     hd = cfg.get("head_dim") or d // h
     return d, h, kv, hd
+
+
+def attention_pairs(cfg: dict, doc_lens) -> int:
+    """Query-key pairs of one packed sequence with documents of
+    ``doc_lens`` tokens: each token attends to itself and every token
+    before it in its document.  This family counts attention over whole
+    documents only; a configuration that states a sliding window is
+    refused here, so that its pairs are never counted as causal ones."""
+    if cfg.get("sliding_window") is not None:
+        raise NotImplementedError("dense attention pairs are counted over "
+                                  "whole documents; this file counts no "
+                                  "sliding window")
+    return causal_pairs(doc_lens)
 
 
 def matmul_params(cfg: dict) -> int:

@@ -6,10 +6,10 @@ file its ``configs`` entry names, its traffic in
 ``bench/limits/<cell>.json``, and each metric in a reader
 ``bench/metrics/<metric>.py`` that defines ``read(rec) -> float | None``.
 The runner of a traffic kind is ``bench/runners/<kind>.py``; the reference
-and the operation counts of a model family are
-``bench/reference/<family>.py`` and ``bench/flops/<family>.py``.  Adding a
-cell, a configuration, a traffic mix or a metric therefore adds files and
-entries and edits none.
+and the operation counts of a model family (its attention pairs among
+them) are ``bench/reference/<family>.py`` and ``bench/flops/<family>.py``.
+Adding a cell, a configuration, a traffic mix or a metric therefore adds
+files and entries and edits none.
 """
 
 from __future__ import annotations

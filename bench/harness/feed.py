@@ -2,12 +2,19 @@
 
 For each stream step the feed draws the packed sequences from the
 benchmark's traffic generator and hands their document lengths to the
-program's public planner and encoder calls, composed as the program's
-``data/pipeline.make_batch`` composes them: the planner resolved by
-``get_planner`` through ``plan_many``, then ``encode_plan_batch``, then
-``emit_visit_tables``; tokens and labels are then permuted from packed
-order into plan order.  The program receives nothing but these generated
-inputs.
+program's planner, encoder and visit-table calls, with the program's own
+``PipelineConfig``: the one ``launch/train.py::pipeline_config`` builds
+from the run's ``ModelConfig`` and ``RunConfig`` (the planner after
+``effective_strategy``, the alignment, whether tables are emitted, their
+overlap and grid).  The calls are composed as the program's
+``data/pipeline.make_batch`` composes them (``plan_many``, then
+``encode_plan_batch``, then ``emit_visit_tables``); tokens and labels are
+then permuted from packed order into plan order.  The program receives
+nothing but these generated inputs.
+
+The plan cache and the table cache stay off, with one planner worker: the
+stream repeats its pass only so that runs are alike, and fresh data in a
+training job would not hit them.
 
 :class:`Feed` runs that work on a background thread, a bounded queue of
 ``depth`` batches ahead of the step loop (the depth of the program's
@@ -19,26 +26,31 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import types
 
 import numpy as np
 
 from .spans import Spans
 from .traffic import SeqSample, Traffic, draw_step
 
-__all__ = ["PlanSpec", "HostBatch", "build_batch", "signature", "Feed"]
+__all__ = ["plan_config", "HostBatch", "build_batch", "signature", "Feed"]
 
 
-@dataclasses.dataclass(frozen=True)
-class PlanSpec:
-    """What the planner side needs from the run configuration."""
-    strategy: str
-    cp: int
-    overlap: str
-    grid: str
-    target_imbalance: float
-    align: int = 128
-    block_q: int = 128
-    block_k: int = 128
+def plan_config(cfg, run, cp: int, traffic: Traffic):
+    """The program's ``PipelineConfig`` for a run of ``traffic`` with the
+    program's ``ModelConfig`` ``cfg`` and ``RunConfig`` ``run`` at CP
+    ``cp``, with its plan cache off and one planner worker."""
+    from repro.launch.train import pipeline_config
+
+    args = types.SimpleNamespace(dataset=traffic.name,
+                                 seq_len=traffic.context_len,
+                                 batch=traffic.batch)
+    pipe = pipeline_config(args, cfg, run, cp)
+    if pipe.target_imbalance != run.target_imbalance:
+        raise NotImplementedError(
+            f"run.target_imbalance {run.target_imbalance!r}: the program's "
+            f"pipeline_config plans to {pipe.target_imbalance!r}")
+    return dataclasses.replace(pipe, cache_plans=False, planner_workers=1)
 
 
 @dataclasses.dataclass
@@ -49,31 +61,40 @@ class HostBatch:
     tokens: int                # tokens of the step (batch x context)
 
 
-def build_batch(spec: PlanSpec, samples: list[SeqSample],
-                step: int) -> HostBatch:
+def build_batch(pipe, samples: list[SeqSample], step: int) -> HostBatch:
+    """The step's arrays, in plan order, for the packed ``samples`` under
+    the program's ``PipelineConfig`` ``pipe`` (see :func:`plan_config`).
+
+    A copy: this mirrors ``_planner_state``, ``_plan``, ``_emit_tables``
+    and ``make_batch`` of the program's ``data/pipeline.py``, which take
+    no given document lengths, and must change with them until the
+    program has one entry for both.  ``tests/bench/test_bench_feed.py``
+    holds the two to the same arrays."""
     from repro.core.cp_attention import resolve_overlap
     from repro.planner import (emit_visit_tables, encode_plan_batch,
                                get_planner, plan_many)
 
-    planner = get_planner(spec.strategy)
+    planner = get_planner(pipe.strategy)
     kwargs = {}
     if planner.info.supports_target_ratio:
-        kwargs["target_ratio"] = spec.target_imbalance
-    plans = plan_many(lambda lens: planner(lens, spec.cp, **kwargs),
-                      [s.doc_lens for s in samples], workers=1)
-    stack, _ = encode_plan_batch(plans, align=spec.align, workers=1)
-    exec_style = planner.info.exec_style
+        kwargs["target_ratio"] = pipe.target_imbalance
+    plans = plan_many(lambda lens: planner(lens, pipe.cp_size, **kwargs),
+                      [s.doc_lens for s in samples],
+                      workers=pipe.planner_workers)
+    stack, _ = encode_plan_batch(plans, buf_len=pipe.buf_len,
+                                 align=pipe.align, workers=1)
     arrays = {k: v for k, v in stack.items() if k != "perm"}
-    arrays.update(emit_visit_tables(
-        stack["doc"], stack["pos"],
-        stack["gath_doc"] if exec_style in ("flashcp", "contiguous")
-        else None,
-        stack["gath_pos"] if exec_style in ("flashcp", "contiguous")
-        else None,
-        num_workers=spec.cp, strategy=exec_style,
-        overlap=resolve_overlap(exec_style, "pallas", spec.overlap),
-        grid=spec.grid, block_q=spec.block_q, block_k=spec.block_k,
-        cache=False))
+    if pipe.emit_tables:
+        exec_style = planner.info.exec_style
+        gath = exec_style in ("flashcp", "contiguous")
+        arrays.update(emit_visit_tables(
+            stack["doc"], stack["pos"],
+            stack["gath_doc"] if gath else None,
+            stack["gath_pos"] if gath else None,
+            num_workers=pipe.cp_size, strategy=exec_style,
+            overlap=resolve_overlap(exec_style, "pallas", pipe.table_overlap),
+            grid=pipe.table_grid, block_q=pipe.table_block_q,
+            block_k=pipe.table_block_k, cache=False))
     perm = stack["perm"]
     tokens = np.full(perm.shape, -1, np.int32)
     labels = np.full(perm.shape, -1, np.int32)
@@ -96,12 +117,11 @@ def signature(arrays: dict) -> tuple:
 class Feed:
     """Background thread producing :class:`HostBatch` es from step 0 on."""
 
-    def __init__(self, traffic: Traffic, spec: PlanSpec, seed: int,
-                 vocab: int, spans: Spans, *, depth: int = 2,
-                 transform=None):
+    def __init__(self, traffic: Traffic, pipe, seed: int, spans: Spans, *,
+                 depth: int = 2, transform=None):
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
-        self._args = (traffic, spec, seed, vocab)
+        self._args = (traffic, pipe, seed)
         self._spans = spans
         self._transform = transform
         self.error: BaseException | None = None
@@ -110,13 +130,13 @@ class Feed:
         self._thread.start()
 
     def _run(self):
-        traffic, spec, seed, vocab = self._args
+        traffic, pipe, seed = self._args
         step = 0
         try:
             while not self._stop.is_set():
-                samples = draw_step(traffic, seed, step, vocab)
+                samples = draw_step(traffic, seed, step, pipe.vocab_size)
                 with self._spans.span("plan_encode"):
-                    hb = build_batch(spec, samples, step)
+                    hb = build_batch(pipe, samples, step)
                 if self._transform is not None:
                     hb = self._transform(hb)
                 while not self._stop.is_set():

@@ -2,8 +2,11 @@
 
 Set-up (all of it counts as ``setup_s``):
 
-1. the model configuration from the cell's file, the run options it pins,
-   the mesh, and the program's train step from
+1. the model configuration from the cell's file (every key reaches the
+   program's ``ModelConfig``, or is a key that describes the file; any
+   other key, or a value the program cannot compute, fails here), the run
+   options it pins, the program's ``PipelineConfig`` built from both, the
+   mesh, and the program's train step from
    ``repro.launch.steps.build_train_step``, jitted as the program's
    ``launch/train.py`` jits it;
 2. the stream's step programs: the feed's planner calls are run over one
@@ -51,11 +54,11 @@ from jax.sharding import Mesh
 from harness import check, weights
 from harness.catalog import Cell, load_module
 from harness.device import memory_peak_bytes
-from harness.feed import Feed, PlanSpec, build_batch, signature
+from harness.feed import Feed, build_batch, plan_config, signature
 from harness.spans import Spans
 from harness.trace import (TraceSummary, events_from_xplane, find_xplane,
                            save_events)
-from harness.traffic import causal_pairs, draw_step
+from harness.traffic import draw_step
 
 __all__ = ["Session", "Trainer", "run", "reference_readings",
            "model_config", "run_config", "TRACE_STEPS", "CHECK_STEPS"]
@@ -68,25 +71,60 @@ TRACE_STEPS = 4
 #: every run queues alike and its window holds the same steps of the stream.
 AHEAD_STEPS = 6
 
-#: configuration-file keys -> ModelConfig fields
+#: configuration-file keys -> ModelConfig fields, where the names differ;
+#: every other model key goes to the field of its own name
 _FIELDS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
            "num_attention_heads": "num_heads",
            "num_key_value_heads": "num_kv_heads",
-           "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-           "head_dim": "head_dim", "rope_theta": "rope_theta",
-           "norm_epsilon": "norm_eps", "torch_dtype": "dtype"}
-_ACT = {"gelu_pytorch_tanh": "gelu"}
+           "intermediate_size": "d_ff", "norm_epsilon": "norm_eps",
+           "torch_dtype": "dtype", "tie_word_embeddings": "tie_embeddings"}
+#: published activations -> the program's MLP that computes them
+_ACT = {"gelu_pytorch_tanh": "gelu", "silu": "glu"}
+#: keys that describe the file rather than the model
+_FILE_KEYS = ("arch", "family", "source", "published", "reduced",
+              "departures", "assumed", "deployment", "mesh", "run",
+              "optimizer", "optimizer_why")
 
 
-def model_config(config: dict):
-    """The program's ModelConfig for a configuration file, every size
-    taken from the file."""
+def model_config(config: dict, context_len: int):
+    """The program's ModelConfig for a configuration file run at
+    ``context_len`` tokens a sequence: each model key of the file reaches
+    the field of its name (or the one ``_FIELDS`` names), and set-up fails,
+    naming the key, on a key that is neither a field nor a key of the file
+    (a published ``sliding_window``, ``use_bias`` or ``norm_type`` among
+    them: the program has no such field), on two keys for one field, on an
+    activation the program does not compute, and on sequences longer than
+    ``max_position_embeddings`` (RoPE keeps no table, so the key sizes
+    nothing else)."""
     from repro.configs import get_config
 
     base = get_config(config["arch"])
-    kw = {f: config[k] for k, f in _FIELDS.items() if k in config}
-    kw["mlp"] = _ACT[config["hidden_act"]]
-    kw["tie_embeddings"] = bool(config["tie_word_embeddings"])
+    fields = {f.name for f in dataclasses.fields(base)}
+    kw, by = {}, {}
+    for key, value in config.items():
+        if key in _FILE_KEYS:
+            continue
+        if key == "max_position_embeddings":
+            if context_len > value:
+                raise ValueError(f"max_position_embeddings {value}: the "
+                                 f"traffic packs {context_len} tokens")
+            continue
+        field = "mlp" if key == "hidden_act" else _FIELDS.get(key, key)
+        if field not in fields:
+            raise ValueError(f"configuration key {key!r} is neither a field "
+                             "of the program's ModelConfig nor a key that "
+                             "describes the file")
+        if field in by:
+            raise ValueError(f"configuration keys {by[field]!r} and {key!r} "
+                             f"both set the field {field!r}")
+        by[field] = key
+        if key == "hidden_act":
+            if value not in _ACT:
+                raise NotImplementedError(
+                    f"hidden_act {value!r}: the program's MLPs compute "
+                    f"{sorted(_ACT)}")
+            value = _ACT[value]
+        kw[field] = value
     return dataclasses.replace(base, **kw)
 
 
@@ -135,7 +173,7 @@ class Session:
         self.cell, self.devices, self.log = cell, list(devices), log
         config, traffic = cell.config, cell.traffic
         self.compiles = _Counter()
-        self.cfg = model_config(config)
+        self.cfg = model_config(config, traffic.context_len)
         self.run_cfg = run_config(config)
         data, model = config["mesh"]["data"], config["mesh"]["model"]
         if data * model != cell.chips:
@@ -153,10 +191,9 @@ class Session:
         (self.params_abs, self.opt_abs, batch_abs,
          self.step_abs) = bundle.abstract_inputs
         self.keys = sorted(batch_abs)
-        self.spec = PlanSpec(strategy=self.run_cfg.cp_strategy, cp=model,
-                             overlap=self.run_cfg.cp_overlap,
-                             grid=self.run_cfg.kernel_grid,
-                             target_imbalance=self.run_cfg.target_imbalance)
+        self.pipeline = plan_config(self.cfg, self.run_cfg, model, traffic)
+        self.flops = load_module(cell.bench_dir / "flops"
+                                 / f"{config['family']}.py")
         self.programs: dict[tuple, object] = {}
         b1 = config["optimizer"]["b1"]
         self.grad_of_mu = jax.jit(lambda mu: (
@@ -173,8 +210,8 @@ class Session:
         t0 = time.perf_counter()
         need = {}
         for s in range(steps):
-            hb = build_batch(self.spec, draw_step(
-                traffic, seed, s, self.cfg.vocab_size), s)
+            hb = build_batch(self.pipeline, draw_step(
+                traffic, seed, s, self.pipeline.vocab_size), s)
             arrs = {k: hb.arrays[k] for k in self.keys}
             sig = signature(arrs)
             if sig not in self.programs:
@@ -216,8 +253,8 @@ class Trainer:
         self.state = {"params": params, "opt": opt}
         del params, opt
         self.log: list[dict] = []
-        self.feed = Feed(session.cell.traffic, session.spec, seed,
-                         session.cfg.vocab_size, spans, transform=transform)
+        self.feed = Feed(session.cell.traffic, session.pipeline, seed, spans,
+                         transform=transform)
 
     def dispatch(self, step: int) -> dict:
         """Send step ``step`` to the chips without waiting for it."""
@@ -250,13 +287,14 @@ class Trainer:
         with self.spans.span("sync"):
             jax.block_until_ready(sent["metrics"])
         te = time.perf_counter()
-        hb = sent["hb"]
+        hb, config = sent["hb"], self.s.cell.config
         rec = {"step": sent["step"],
                "s": te - (sent["ts"] if since is None else max(since,
                                                                sent["ts"])),
                "end": te, "loss": float(sent["metrics"]["loss"]),
                "tokens": hb.tokens,
-               "pairs": sum(causal_pairs(x.doc_lens) for x in hb.samples)}
+               "pairs": sum(self.s.flops.attention_pairs(config, x.doc_lens)
+                            for x in hb.samples)}
         self.log.append(rec)
         return rec
 
@@ -360,7 +398,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices, *,
         trainer.close()
 
     # -- free the program's state, then the reference -------------------- #
-    params_abs = session.params_abs
+    params_abs, flops, pipeline = (session.params_abs, session.flops,
+                                   session.pipeline)
     del session
     gc.collect()
     reference = load_module(cell.bench_dir / "reference"
@@ -375,10 +414,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices, *,
     failed = sum(not math.isfinite(r["loss"]) for r in window)
 
     rec = types.SimpleNamespace(
-        cell=cell, config=cell.config, chips=cell.chips,
-        flops=load_module(cell.bench_dir / "flops"
-                          / f"{cell.config['family']}.py"),
-        peaks=None, setup_s=setup_s, window_s=t_we - t_ws, window=window,
+        cell=cell, config=cell.config, chips=cell.chips, flops=flops,
+        pipeline=pipeline, peaks=None, setup_s=setup_s,
+        window_s=t_we - t_ws, window=window,
         spans=spans, window_ns=(ws_ns, we_ns), trace=summary,
         traced=traced, in_window_compiles=in_window_compiles,
         bench_dir=cell.bench_dir)

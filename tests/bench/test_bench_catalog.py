@@ -9,8 +9,12 @@ import shutil
 import pytest
 
 from bench_tiny import BENCH, REPO, make_root, run_tiny
+from harness.traffic import draw_step
 from harness import check
 from harness.catalog import load_benchmark, load_cell, metric_reader
+
+
+SEED = 2**33 + 11
 
 
 def test_committed_cells_load():
@@ -38,9 +42,19 @@ def test_new_config_traffic_and_metric_as_files(tmp_path):
     shutil.copytree(BENCH / "metrics", b / "metrics")
     (b / "metrics" / "window_steps.py").write_text(
         "def read(rec):\n    return len(rec.window)\n")
+    # a new model family: its reference and its counts, as files; the
+    # family's own count of attention pairs fills each step's record
+    for d in ("flops", "reference"):
+        (b / d).unlink()
+        shutil.copytree(BENCH / d, b / d)
+        shutil.copy(b / d / "dense.py", b / d / "stub.py")
+    with open(b / "flops" / "stub.py", "a") as f:
+        f.write("\n\ndef attention_pairs(cfg, doc_lens):\n"
+                "    return 1000 + len(doc_lens)\n")
     # a new configuration and a new traffic mix, as files
     cfg = json.loads((b / "configs" / "tiny.json").read_text())
     cfg["num_hidden_layers"] = 1
+    cfg["family"] = "stub"
     (b / "configs" / "tiny_one.json").write_text(json.dumps(cfg))
     tr = json.loads((b / "traffic" / "tiny.json").read_text())
     tr["docs"] = [{"p": 1.0, "dist": "lognormal", "mu": 3.5, "sigma": 0.5,
@@ -70,11 +84,15 @@ def test_new_config_traffic_and_metric_as_files(tmp_path):
                                   load_cell("tiny.t", root).per_layer]
 
     import run as bench_run
-    out = run_tiny(root, workload="tiny_one.short")
+    out = run_tiny(root, workload="tiny_one.short", seed=SEED)
     rec = out["record"]
     rec.peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
     got = bench_run.metric_values(cell, cell.per_layer, rec)
     assert got["window_steps"]["value"] == out["attempted"] >= 1
     assert got["window_steps"]["unit"] == "steps"
+    assert rec.window and all(
+        r["pairs"] == sum(1000 + len(x.doc_lens) for x in draw_step(
+            cell.traffic, SEED, r["step"], cfg["vocab_size"]))
+        for r in rec.window)
     # trace readers find nothing to read without a trace, and stay silent
     assert "device_idle_pct" not in got and "attn_roofline" not in got

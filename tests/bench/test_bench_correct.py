@@ -102,7 +102,7 @@ def test_float8_control_fails(tmp_path):
     cell = load_cell("tiny.t", root)
     runner = load_module(cell.bench_dir / "runners" / "train.py")
     ref_mod = load_module(cell.bench_dir / "reference" / "dense.py")
-    cfg = runner.model_config(cell.config)
+    cfg = runner.model_config(cell.config, cell.traffic.context_len)
     bundle = build_train_step(
         cfg, make_local_mesh(1, 1), runner.run_config(cell.config),
         ShapeConfig("t", cell.traffic.context_len, 1, "train"))
@@ -135,7 +135,8 @@ def test_reference_computes_with_stored_weights(tmp_path):
     cell = load_cell("tiny.t", make_root(tmp_path))
     runner = load_module(cell.bench_dir / "runners" / "train.py")
     ref_mod = load_module(cell.bench_dir / "reference" / "dense.py")
-    params_abs, _ = _abstract_state(runner.model_config(cell.config))
+    params_abs, _ = _abstract_state(runner.model_config(
+        cell.config, cell.traffic.context_len))
     master = weights.make_init(params_abs, widen=True)(
         weights.seed_words(2**40 + 3))
     batch = ref_mod.packed_inputs(draw_step(cell.traffic, 5, 0,

@@ -29,6 +29,26 @@ def test_causal_pairs_by_hand():
     assert causal_pairs([8192]) == 8192 * 8193 // 2
 
 
+def test_attention_pairs_by_hand():
+    assert flops.attention_pairs(TINY, [1]) == 1
+    assert flops.attention_pairs(TINY, np.array([3, 2])) == 6 + 3
+    assert flops.attention_pairs(TINY, [131072]) == 131072 * 131073 // 2
+    with pytest.raises(NotImplementedError):
+        flops.attention_pairs(dict(TINY, sliding_window=4), [8])
+
+
+def test_dense_attention_pairs_are_causal_pairs_on_cell_1():
+    """On every step of a pass of cell 1's traffic, and of the next pass,
+    the family's count is the causal count the runner used to record."""
+    tr = _traffic("wlb_llm.8k")
+    cfg = json.loads((BENCH / "configs" / "starcoder2_3b.json").read_text())
+    for step in range(2 * tr.stream_steps):
+        for s in draw_step(tr, 2**33 + 5, step, 49152):
+            got = flops.attention_pairs(cfg, s.doc_lens)
+            assert type(got) is int
+            assert got == causal_pairs(s.doc_lens)
+
+
 def test_matmul_params_by_hand():
     # per layer: q 8*8, k 8*4, v 8*4, o 8*8, mlp 2*8*16 -> 448; head 80
     assert flops.matmul_params(TINY) == 2 * 448 + 80

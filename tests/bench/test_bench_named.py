@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from bench_tiny import BENCH, make_root, run_tiny
-from harness.catalog import load_cell, metric_reader
-from harness.feed import PlanSpec, build_batch
+from harness.catalog import load_cell, load_module, metric_reader
+from harness.feed import build_batch, plan_config
 from harness.named import base_name, kernel_intervals
 from harness.trace import TraceSummary, kernel_names
 from harness.traffic import causal_pairs, draw_step
@@ -116,18 +116,21 @@ def test_table_counters_recount(tiny_cell):
     from repro.table_layout import FLAG_VALID
 
     traffic = tiny_cell.traffic
-    spec = PlanSpec(strategy="flashcp", cp=2, overlap="chunked",
-                    grid="flat", target_imbalance=1.05)
+    runner = load_module(tiny_cell.bench_dir / "runners" / "train.py")
+    pipe = plan_config(runner.model_config(tiny_cell.config,
+                                            traffic.context_len),
+                       runner.run_config(tiny_cell.config), 2, traffic)
     t0 = obs.records()[-1].end_ns + 1 if obs.records() else 0
     visits = slots = pairs = 0
     for step in range(3):
         samples = draw_step(traffic, 5, step, 512)
-        arrays = build_batch(spec, samples, step).arrays
+        arrays = build_batch(pipe, samples, step).arrays
         flags = [a for k, a in arrays.items() if k.endswith("_flags")]
         visits += sum(int(np.count_nonzero(f & FLAG_VALID)) for f in flags)
         slots += sum(f.size for f in flags)
         pairs += sum(causal_pairs(s.doc_lens) for s in samples)
-    rec = types.SimpleNamespace(window_ns=(t0, obs.records()[-1].end_ns))
+    rec = types.SimpleNamespace(window_ns=(t0, obs.records()[-1].end_ns),
+                                pipeline=pipe)
     tables = [r for r in obs.records(*rec.window_ns)
               if r.name == "planner.tables"]
     assert len(tables) == 3
